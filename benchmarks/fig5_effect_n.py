@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import brute_force
+from repro.jit_cache import enable_compile_cache
 
 from .common import DEFAULT_K, load_dataset, methods_for, recall_and_ratio, timed
 
@@ -56,4 +57,5 @@ def main(fractions=(0.25, 0.5, 1.0)):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

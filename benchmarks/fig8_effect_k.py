@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core import brute_force
+from repro.jit_cache import enable_compile_cache
 
 from .common import load_dataset, methods_for, recall_and_ratio, timed
 
@@ -33,4 +34,5 @@ def main(ks=(1, 10, 50)):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core import brute_force, search_batch_fixed
+from repro.jit_cache import enable_compile_cache
 
 from .common import DEFAULT_K, build_dblsh, load_dataset, recall_and_ratio, timed
 
@@ -41,4 +42,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

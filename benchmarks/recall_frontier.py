@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.core import DBLSHParams, Termination, brute_force, build, search_batch_fixed
 from repro.data import make_clustered, normalize_scale
+from repro.jit_cache import enable_compile_cache
 from repro.tune import (
     RecallTarget,
     calibrate,
@@ -270,4 +271,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

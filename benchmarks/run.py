@@ -102,4 +102,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.jit_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

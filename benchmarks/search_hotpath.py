@@ -68,6 +68,7 @@ from repro.core import (
 )
 from repro.core.serve_search import _select_blocks
 from repro.data import make_clustered, normalize_scale
+from repro.jit_cache import enable_compile_cache
 
 try:  # module run (benchmarks.run) vs script run (python benchmarks/...)
     from .common import recall_at, timed
@@ -372,4 +373,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -69,7 +69,9 @@ except ImportError:
     # python benchmarks/store_throughput.py
     from common import load_dataset, recall_and_ratio
 
+from repro.compat import make_mesh
 from repro.core import brute_force
+from repro.jit_cache import enable_compile_cache
 from repro.obs import DEFAULT_EXPLAIN_SAMPLE_RATE, Observability, Tracer
 from repro.obs.metrics import MetricsRegistry
 from repro.store import (
@@ -677,7 +679,7 @@ def bench_sharded_updates(
         scale, n_queries, rounds = min(scale, 0.05), 32, 2
     data, queries = load_dataset(dataset, scale=scale)
     pn = len(jax.devices())
-    mesh = jax.make_mesh((pn,), ("data",))
+    mesh = make_mesh((pn,), ("data",))
     n_pool = data.shape[0]
     n_base = (int(n_pool * 0.75) // pn) * pn
     base, pool = data[:n_base], data[n_base:]
@@ -782,7 +784,7 @@ def bench_sharded_updates(
         pn_new = pn // 2
         tmpdir = tempfile.mkdtemp(prefix="sharded_bench_snap_")
         step = col.snapshot(tmpdir)
-        mesh2 = jax.make_mesh((pn_new,), ("data",))
+        mesh2 = make_mesh((pn_new,), ("data",))
         t0 = time.perf_counter()
         col2 = ShardedCollection.restore(tmpdir, mesh=mesh2, step=step)
         col2.live_count()
@@ -915,6 +917,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.2)
     ap.add_argument("--dataset", default="sift-s")

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import brute_force
+from repro.jit_cache import enable_compile_cache
 
 from .common import DEFAULT_K, SCALED_DATASETS, load_dataset, methods_for, recall_and_ratio, timed
 
@@ -46,4 +47,5 @@ def main(scale=0.5):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -21,7 +21,9 @@ import jax
 import numpy as np
 
 from benchmarks.common import load_dataset, recall_and_ratio
+from repro.compat import make_mesh
 from repro.core import brute_force
+from repro.jit_cache import enable_compile_cache
 from repro.store import (
     Collection,
     CompactionPolicy,
@@ -119,7 +121,7 @@ def main(scale: float = 0.25, dataset: str = "sift-s"):
     # identical at any P); the service serves it through the same queue,
     # cache, and policy path as the local collection above.
     pn = len(jax.devices())
-    mesh = jax.make_mesh((pn,), ("data",))
+    mesh = make_mesh((pn,), ("data",))
     n_shard = (base.shape[0] // pn) * pn
     sc = ShardedCollection.create(
         "demo-sharded", jax.random.key(2), base[:n_shard], mesh,
@@ -144,6 +146,7 @@ def main(scale: float = 0.25, dataset: str = "sift-s"):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.25)
     ap.add_argument("--dataset", default="sift-s")

@@ -10,6 +10,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticTokens, make_batch_fn
+from repro.jit_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serve import Request, RetrievalLM, ServeEngine, build_datastore
 
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ import numpy as np
 
 from repro.core import DBLSHParams, brute_force, build, search_batch, search_batch_fixed
 from repro.data import make_clustered, normalize_scale
+from repro.jit_cache import enable_compile_cache
 
 
 def main():
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

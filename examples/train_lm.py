@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticTokens, make_batch_fn
+from repro.jit_cache import enable_compile_cache
 from repro.models.registry import build_model, param_count
 from repro.runtime import TrainSupervisor
 from repro.train import init_train_state, make_optimizer, make_train_step
@@ -58,4 +59,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -1,45 +1,39 @@
-"""jax cross-version compatibility (0.4.x <-> >= 0.6).
+"""The repo's single doorway into jax's sharding API (jax 0.9).
 
-Two API moves matter to this repo:
+* :func:`shard_map` wraps ``jax.shard_map`` with the replication (VMA)
+  check off by default: every call site predates it and relies on
+  manual spec correctness.
+* :func:`make_mesh` wraps ``jax.make_mesh`` with every axis **Auto**.
+  ``jax.make_mesh`` now defaults to Explicit axes, under which a
+  ``jnp.take`` on a data-sharded operand raises ``ShardingTypeError``;
+  every placement in this repo is written for Auto, where the compiler
+  propagates shardings.
 
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, and its replication-check kwarg was renamed
-  (``check_rep`` -> ``check_vma``);
-* ``jax.make_mesh`` grew an ``axis_types`` parameter. Both versions
-  default every axis to Auto, so callers that want Auto simply omit it.
-
-Import :func:`shard_map` from here instead of from ``jax`` directly.
+Build meshes and shard_maps through here, not through ``jax`` directly.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map"]
+__all__ = ["make_mesh", "shard_map"]
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-    _NOCHECK = {"check_vma": False}
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    _NOCHECK = {"check_rep": False}
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check: bool = False):
-    """``jax.shard_map`` with the version-appropriate kwarg spellings.
-
-    ``check=False`` (the repo default) disables the replication/VMA
-    check — every call site here predates it and relies on manual spec
-    correctness.  ``axis_names`` selects *partial manual* mode (manual
-    over the named axes only); jax 0.4.x spells that as the complement,
-    ``auto=<other axes>``.
-    """
-    kw = {} if check else dict(_NOCHECK)
+    """``jax.shard_map`` with ``check_vma=check``; ``axis_names`` selects
+    *partial manual* mode (manual over the named axes only)."""
+    kw = {"check_vma": check}
     if axis_names is not None:
-        if hasattr(jax, "shard_map"):
-            kw["axis_names"] = set(axis_names)
-        else:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

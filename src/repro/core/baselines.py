@@ -34,10 +34,13 @@ _INF = jnp.inf
 @partial(jax.jit, static_argnames=("k",))
 def brute_force(data: jax.Array, Q: jax.Array, k: int = 50):
     """Exact k-NN via a blocked distance matrix. Returns (dists, ids)."""
-    # ||q - x||^2 = ||q||^2 - 2 q.x + ||x||^2  (MXU-friendly)
+    # ||q - x||^2 = ||q||^2 - 2 q.x + ||x||^2  (MXU-friendly).  HIGHEST:
+    # the oracle must be exact on a TPU too, where a DEFAULT f32 matmul
+    # is a single bf16 pass
     qn = jnp.sum(jnp.square(Q), axis=-1, keepdims=True)  # (Qn,1)
     xn = jnp.sum(jnp.square(data), axis=-1)  # (n,)
-    d2 = qn - 2.0 * Q @ data.T + xn  # (Qn, n)
+    dots = jnp.matmul(Q, data.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = qn - 2.0 * dots + xn  # (Qn, n)
     d2 = jnp.maximum(d2, 0.0)
     neg, ids = jax.lax.top_k(-d2, k)
     return jnp.sqrt(-neg), ids

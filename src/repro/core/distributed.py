@@ -487,10 +487,7 @@ def compact_sharded(
     pos = o - lo[srcs, np.arange(pn)[:, None]]
     valid = np.arange(n_keep)[None, :] < targets[:, None]
     reasm = np.where(valid, srcs * bucket + pos, pn * bucket).astype(np.int64)
-    new_params = DBLSHParams.derive(
-        n=n_keep, d=p.d, c=p.c, w0=p.w0, t=p.t, k=p.k,
-        block_size=p.block_size, inline_vectors=p.inline_vectors,
-    )
+    new_params = p.rebuilt(n_keep)
     idx, id_map = _compact_sharded_jit(
         s, key,
         jnp.asarray(targets, jnp.int32),

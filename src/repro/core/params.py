@@ -111,6 +111,8 @@ class DBLSHParams:
     use_kernel: bool = False      # route verification through the Pallas kernel
     quant_dtype: str = "none"     # 'bf16'/'int8': keep quantized vec blocks for
                                   # the reduced-precision distance path
+    fixed_KL: bool = False        # K and L were given, not derived: a rebuild
+                                  # at a new n keeps them (see rebuilt)
 
     # --- derived (filled by .resolve()) ---
     p1: float = 0.0
@@ -135,6 +137,7 @@ class DBLSHParams:
         p2 = _p(c, w0)
         rho = rho_star(c, w0)
         nt = max(n / max(t, 1), 2.0)
+        kw.setdefault("fixed_KL", K > 0 and L > 0)
         if K <= 0:
             K = max(2, math.ceil(math.log(nt) / _log_inv_p(c, w0)))
         if L <= 0:
@@ -166,6 +169,18 @@ class DBLSHParams:
         if not upd:
             return self
         return dataclasses.replace(self, **upd)
+
+    def rebuilt(self, n: int) -> "DBLSHParams":
+        """Params for a rebuild of this index over ``n`` live points
+        (compaction, elastic restore): a derived K/L follows n (K ~ log
+        n), a K/L the caller fixed stays."""
+        keep = self.fixed_KL
+        return DBLSHParams.derive(
+            n=n, d=self.d, c=self.c, w0=self.w0, t=self.t, k=self.k,
+            K=self.K if keep else 0, L=self.L if keep else 0,
+            block_size=self.block_size, inline_vectors=self.inline_vectors,
+            quant_dtype=self.quant_dtype,
+        )
 
     @property
     def cand_per_table(self) -> int:

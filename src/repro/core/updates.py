@@ -17,7 +17,7 @@ updates. The dense STR-block structure supports them naturally:
   id is invalid); space is reclaimed at the next compact.
 
 * **compact** — rebuild from the surviving points with a fresh key
-  (also re-derives K/L for the current n).
+  (also re-derives K/L for the current n, unless the caller fixed them).
 """
 
 from __future__ import annotations
@@ -179,7 +179,8 @@ def live_ids_padded(index: DBLSHIndex) -> jax.Array:
 
 
 def compact(index: DBLSHIndex, key) -> tuple[DBLSHIndex, jax.Array]:
-    """Rebuild from surviving points (re-derives K/L for the live n).
+    """Rebuild from surviving points (re-derives a derived K/L for the
+    live n; a fixed one stays — :meth:`DBLSHParams.rebuilt`).
 
     Returns (new_index, id_map) where id_map (n_old,) holds each old
     id's new id, or -1 if deleted."""
@@ -189,11 +190,7 @@ def compact(index: DBLSHIndex, key) -> tuple[DBLSHIndex, jax.Array]:
     live_ids = live_ids[live_ids < n_old]
     n_live = int(live_ids.shape[0])
     data = jnp.take(index.data, live_ids, axis=0)
-    new_params = DBLSHParams.derive(
-        n=n_live, d=p.d, c=p.c, w0=p.w0, t=p.t, k=p.k,
-        block_size=p.block_size, inline_vectors=p.inline_vectors,
-        quant_dtype=p.quant_dtype,
-    )
+    new_params = p.rebuilt(n_live)
     id_map = jnp.full((n_old,), -1, jnp.int32)
     id_map = id_map.at[live_ids].set(jnp.arange(n_live, dtype=jnp.int32))
     return build(key, data, new_params), id_map

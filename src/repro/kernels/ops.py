@@ -15,6 +15,7 @@ from jax.experimental import pallas as pl
 
 from .pairwise_l2 import pairwise_l2_kernel
 from .window_verify import (
+    _ROWS,
     candidate_dist_kernel,
     candidate_verify_kernel,
     fused_cand_kernel,
@@ -305,6 +306,7 @@ def fused_window_search(blk_idx, halves, proj_blocks, x_blocks, norm_blocks,
 
     Qn, S = blk_idx.shape
     lnb, B, K = proj_blocks.shape
+    L = g.shape[1]
     d = x_blocks.shape[-1]
     steps = halves.shape[0]
     halves2 = halves.reshape(1, steps).astype(jnp.float32)
@@ -315,27 +317,34 @@ def fused_window_search(blk_idx, halves, proj_blocks, x_blocks, norm_blocks,
     def _route(blk, qi, s):
         return jnp.where(blk[qi, s] < lnb, blk[qi, s], 0)
 
+    # TPU blocks need their last two dims (8, 128)-aligned or equal to the
+    # array's: per-query operands get a unit sublane axis, and the (L*nb,
+    # B) per-slot rows are read as the aligned 8-row tile holding the
+    # selected block (the kernel picks row blk % 8) — no relayout of the
+    # index arrays.
+    row_tile = pl.BlockSpec(
+        (_ROWS, B), lambda qi, s, blk: (_route(blk, qi, s) // _ROWS, 0)
+    )
     in_specs = [
         pl.BlockSpec((1, steps), lambda qi, s, blk: (0, 0)),  # halves
-        pl.BlockSpec((1, 1, K), lambda qi, s, blk: (qi, s // M, 0)),  # g
-        pl.BlockSpec((1, d), lambda qi, s, blk: (qi, 0)),  # q
-        pl.BlockSpec((1, 1), lambda qi, s, blk: (qi, 0)),  # q2
+        pl.BlockSpec((1, 1, 1, K), lambda qi, s, blk: (qi, s // M, 0, 0)),  # g
+        pl.BlockSpec((1, 1, d), lambda qi, s, blk: (qi, 0, 0)),  # q
+        pl.BlockSpec((1, 1, 1), lambda qi, s, blk: (qi, 0, 0)),  # q2
     ]
-    operands = [halves2, g, qv, q2]
+    operands = [halves2, g.reshape(Qn, L, 1, K), qv.reshape(Qn, 1, d),
+                q2.reshape(Qn, 1, 1)]
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1), lambda qi, s, blk: (qi, 0)))
-        operands.append(qs)
+        in_specs.append(pl.BlockSpec((1, 1, 1), lambda qi, s, blk: (qi, 0, 0)))
+        operands.append(qs.reshape(Qn, 1, 1))
     in_specs += [
         pl.BlockSpec((1, B, K), lambda qi, s, blk: (_route(blk, qi, s), 0, 0)),
         pl.BlockSpec((1, B, d), lambda qi, s, blk: (_route(blk, qi, s), 0, 0)),
-        pl.BlockSpec((1, B), lambda qi, s, blk: (_route(blk, qi, s), 0)),
-        pl.BlockSpec((1, B), lambda qi, s, blk: (_route(blk, qi, s), 0)),
+        row_tile,  # norms
+        row_tile,  # ids
     ]
     operands += [proj_blocks, x_blocks, norm_blocks, ids_blocks]
     if quant:
-        in_specs.append(
-            pl.BlockSpec((1, B), lambda qi, s, blk: (_route(blk, qi, s), 0))
-        )
+        in_specs.append(row_tile)
         operands.append(x_scale)
 
     kern = functools.partial(
@@ -348,7 +357,7 @@ def fused_window_search(blk_idx, halves, proj_blocks, x_blocks, norm_blocks,
         out_specs=[
             pl.BlockSpec((1, steps, ks), lambda qi, s, blk: (qi, 0, 0)),
             pl.BlockSpec((1, steps, ks), lambda qi, s, blk: (qi, 0, 0)),
-            pl.BlockSpec((1, steps), lambda qi, s, blk: (qi, 0)),
+            pl.BlockSpec((1, 1, steps), lambda qi, s, blk: (qi, 0, 0)),
         ],
     )
     bd, bi, cnt = pl.pallas_call(
@@ -357,11 +366,11 @@ def fused_window_search(blk_idx, halves, proj_blocks, x_blocks, norm_blocks,
         out_shape=[
             jax.ShapeDtypeStruct((Qn, steps, ks), jnp.float32),
             jax.ShapeDtypeStruct((Qn, steps, ks), jnp.int32),
-            jax.ShapeDtypeStruct((Qn, steps), jnp.int32),
+            jax.ShapeDtypeStruct((Qn, 1, steps), jnp.int32),
         ],
         interpret=_interp(interpret),
     )(blk_idx, *operands)
-    return bd, jnp.where(bi == _IMAX, n, bi), cnt
+    return bd, jnp.where(bi == _IMAX, n, bi), cnt.reshape(Qn, steps)
 
 
 @functools.partial(
@@ -396,29 +405,32 @@ def fused_cand_search(cand_proj, cand_x, cand_norms, cand_ids, halves, g, q,
     qv, qs = _quantize_query(q, mode)
     quant = mode in ("bf16", "int8")
 
+    # TPU-legal blocks: every per-query / per-slot operand gets a unit
+    # sublane axis so its block's last two dims equal the array's
+    slot = pl.BlockSpec((1, 1, 1, tile_c), lambda qi, l, t: (qi, l, 0, t))
     in_specs = [
         pl.BlockSpec((1, steps), lambda qi, l, t: (0, 0)),  # halves
-        pl.BlockSpec((1, 1, K), lambda qi, l, t: (qi, l, 0)),  # g
-        pl.BlockSpec((1, d), lambda qi, l, t: (qi, 0)),  # q
-        pl.BlockSpec((1, 1), lambda qi, l, t: (qi, 0)),  # q2
+        pl.BlockSpec((1, 1, 1, K), lambda qi, l, t: (qi, l, 0, 0)),  # g
+        pl.BlockSpec((1, 1, d), lambda qi, l, t: (qi, 0, 0)),  # q
+        pl.BlockSpec((1, 1, 1), lambda qi, l, t: (qi, 0, 0)),  # q2
     ]
-    operands = [halves2, g, qv, q2]
+    operands = [halves2, g.reshape(Qn, L, 1, K), qv.reshape(Qn, 1, d),
+                q2.reshape(Qn, 1, 1)]
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1), lambda qi, l, t: (qi, 0)))
-        operands.append(qs)
+        in_specs.append(pl.BlockSpec((1, 1, 1), lambda qi, l, t: (qi, 0, 0)))
+        operands.append(qs.reshape(Qn, 1, 1))
     in_specs += [
         pl.BlockSpec((1, 1, tile_c, K), lambda qi, l, t: (qi, l, t, 0)),
         pl.BlockSpec((1, 1, tile_c, d), lambda qi, l, t: (qi, l, t, 0)),
-        pl.BlockSpec((1, 1, tile_c), lambda qi, l, t: (qi, l, t)),
-        pl.BlockSpec((1, 1, tile_c), lambda qi, l, t: (qi, l, t)),
+        slot,  # norms
+        slot,  # ids
     ]
-    operands += [cand_proj, cand_x, cand_norms, cand_ids]
+    operands += [cand_proj, cand_x, cand_norms.reshape(Qn, L, 1, Cp),
+                 cand_ids.reshape(Qn, L, 1, Cp)]
     if quant:
         cand_scale = _pad_to(cand_scale, tile_c, 2, 1.0)
-        in_specs.append(
-            pl.BlockSpec((1, 1, tile_c), lambda qi, l, t: (qi, l, t))
-        )
-        operands.append(cand_scale)
+        in_specs.append(slot)
+        operands.append(cand_scale.reshape(Qn, L, 1, Cp))
 
     kern = functools.partial(fused_cand_kernel, steps=steps, ks=ks, mode=mode)
     bd, bi, cnt = pl.pallas_call(
@@ -428,16 +440,16 @@ def fused_cand_search(cand_proj, cand_x, cand_norms, cand_ids, halves, g, q,
         out_specs=[
             pl.BlockSpec((1, steps, ks), lambda qi, l, t: (qi, 0, 0)),
             pl.BlockSpec((1, steps, ks), lambda qi, l, t: (qi, 0, 0)),
-            pl.BlockSpec((1, steps), lambda qi, l, t: (qi, 0)),
+            pl.BlockSpec((1, 1, steps), lambda qi, l, t: (qi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Qn, steps, ks), jnp.float32),
             jax.ShapeDtypeStruct((Qn, steps, ks), jnp.int32),
-            jax.ShapeDtypeStruct((Qn, steps), jnp.int32),
+            jax.ShapeDtypeStruct((Qn, 1, steps), jnp.int32),
         ],
         interpret=_interp(interpret),
     )(*operands)
-    return bd, jnp.where(bi == _IMAX, n, bi), cnt
+    return bd, jnp.where(bi == _IMAX, n, bi), cnt.reshape(Qn, steps)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_q", "tile_n", "tile_d", "interpret"))

@@ -78,6 +78,7 @@ from jax.experimental import pallas as pl
 
 _INF = jnp.inf
 _IMAX = jnp.iinfo(jnp.int32).max
+_ROWS = 8  # sublane tile: the row granularity a TPU block may start at
 
 
 def merge_topk(cd, ci, out_d, out_i, k: int):
@@ -279,15 +280,18 @@ def _slot_d2(x, q, nrm, q2, *, mode: str, xscale=None, qscale=None):
         diff = x - q[None, :]
         return jnp.sum(diff * diff, axis=-1)
     if mode == "norm":
-        return jnp.maximum(nrm - 2.0 * jnp.dot(x, q) + q2, 0.0)
-    if mode == "int8":
-        dot = jnp.dot(x, q, preferred_element_type=jnp.int32).astype(
-            jnp.float32
-        )
-    elif mode == "bf16":
-        dot = jnp.dot(x, q, preferred_element_type=jnp.float32)
-    else:  # pragma: no cover - guarded by the wrappers
+        # HIGHEST: a DEFAULT-precision f32 dot runs as one bf16 pass on the
+        # MXU, which the norm form's cancellation turns into a visible
+        # distance error
+        dot = jnp.dot(x, q, precision=jax.lax.Precision.HIGHEST)
+        return jnp.maximum(nrm - 2.0 * dot + q2, 0.0)
+    if mode not in ("int8", "bf16"):  # pragma: no cover - wrapper-guarded
         raise ValueError(f"unknown distance mode {mode!r}")
+    # (1, d) x (C, d)^T: the matmul form Mosaic lowers for narrow dtypes
+    dot = jax.lax.dot_general(
+        q[None, :], x, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32 if mode == "int8" else jnp.float32,
+    )[0].astype(jnp.float32)
     return jnp.maximum(nrm - 2.0 * (xscale * qscale * dot) + q2, 0.0)
 
 
@@ -303,16 +307,17 @@ def _fused_slot_update(hw, d2, ids, halves, bd_ref, bi_ref, cnt_ref, *,
     bins.  ``cnt`` accumulates admitted candidate slots per bin; its
     cumulative sum equals the C1 admission count ``#{hw <= w_j/2}``.
 
-    ``bd/bi`` are (1, steps, ks) accumulators revisited across the slot
-    axis of the grid; ``merge_topk``'s drop-equal-(dist, id) step dedups
-    cross-table duplicates within a bin exactly as the flat merge does.
+    ``bd/bi`` are (1, steps, ks) and ``cnt`` (1, 1, steps) accumulators
+    revisited across the slot axis of the grid; ``merge_topk``'s
+    drop-equal-(dist, id) step dedups cross-table duplicates within a bin
+    exactly as the flat merge does.
     """
     c = hw.shape[0]
     binid = jnp.sum((hw[None, :] > halves[:, None]).astype(jnp.int32), axis=0)
     # 2D iota (broadcasted_iota): 1D iota does not lower on TPU
     stepv = jax.lax.broadcasted_iota(jnp.int32, (steps, c), 0)
     hits = binid[None, :] == stepv  # (steps, C)
-    cnt_ref[0] = cnt_ref[0] + jnp.sum(hits.astype(jnp.int32), axis=1)
+    cnt_ref[0, 0] = cnt_ref[0, 0] + jnp.sum(hits.astype(jnp.int32), axis=1)
     for j in range(steps):
         m = binid == j
 
@@ -331,12 +336,15 @@ def fused_window_kernel(*refs, lnb: int, steps: int, ks: int, mode: str):
 
     Grid (Q, S).  Scalar-prefetch block DMA exactly as
     ``window_dist_kernel``; candidates never reach HBM — the only
-    outputs are the (1, steps, ks) bin accumulators and the (1, steps)
+    outputs are the (1, steps, ks) bin accumulators and the (1, 1, steps)
     admitted-slot counters, revisited across the S slot steps.
 
-    Quantized modes take two extra refs: the per-query quant scale
-    (qs, (1,1)) after q2 and the per-slot dequant scales (scl, (1,B))
-    after ids."""
+    Blocks: g (1,1,1,K) of the slot's table, q (1,1,d), q2 (1,1,1); proj
+    (1,B,K) and vec (1,B,d) of the selected block; norms and ids arrive
+    as the aligned (8,B) row tile holding the block, of which row
+    ``blk % 8`` is the block's.  Quantized modes take two extra refs: the
+    per-query quant scale (qs, (1,1,1)) after q2 and the per-slot dequant
+    scales (scl, an (8,B) row tile) after ids."""
     quant = mode in ("bf16", "int8")
     if quant:
         (blk_ref, halves_ref, g_ref, q_ref, q2_ref, qs_ref,
@@ -355,19 +363,23 @@ def fused_window_kernel(*refs, lnb: int, steps: int, ks: int, mode: str):
         bi_ref[...] = jnp.full_like(bi_ref, _IMAX)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    @pl.when(blk_ref[qi, s] < lnb)
+    blk = blk_ref[qi, s]
+
+    @pl.when(blk < lnb)
     def _compute():
+        row = pl.ds(blk % _ROWS, 1)
         p = proj_ref[0]  # (B, K)
-        g = g_ref[0, 0]  # (K,)
+        g = g_ref[0, 0, 0]  # (K,)
         hw = jnp.max(jnp.abs(p - g[None, :]), axis=-1)  # (B,)
         d2 = _slot_d2(
-            vec_ref[0], q_ref[0], nrm_ref[0], q2_ref[0, 0], mode=mode,
-            xscale=scl_ref[0] if quant else None,
-            qscale=qs_ref[0, 0] if quant else None,
+            vec_ref[0], q_ref[0, 0], nrm_ref[row, :][0], q2_ref[0, 0, 0],
+            mode=mode,
+            xscale=scl_ref[row, :][0] if quant else None,
+            qscale=qs_ref[0, 0, 0] if quant else None,
         )
         _fused_slot_update(
-            hw, d2, ids_ref[0], halves_ref[0], bd_ref, bi_ref, cnt_ref,
-            steps=steps, ks=ks,
+            hw, d2, ids_ref[row, :][0], halves_ref[0], bd_ref, bi_ref,
+            cnt_ref, steps=steps, ks=ks,
         )
 
 
@@ -395,14 +407,15 @@ def fused_cand_kernel(*refs, steps: int, ks: int, mode: str):
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     p = proj_ref[0, 0]  # (TC, K)
-    g = g_ref[0, 0]  # (K,)
+    g = g_ref[0, 0, 0]  # (K,)
     hw = jnp.max(jnp.abs(p - g[None, :]), axis=-1)  # (TC,)
     d2 = _slot_d2(
-        vec_ref[0, 0], q_ref[0], nrm_ref[0, 0], q2_ref[0, 0], mode=mode,
-        xscale=scl_ref[0, 0] if quant else None,
-        qscale=qs_ref[0, 0] if quant else None,
+        vec_ref[0, 0], q_ref[0, 0], nrm_ref[0, 0, 0], q2_ref[0, 0, 0],
+        mode=mode,
+        xscale=scl_ref[0, 0, 0] if quant else None,
+        qscale=qs_ref[0, 0, 0] if quant else None,
     )
     _fused_slot_update(
-        hw, d2, ids_ref[0, 0], halves_ref[0], bd_ref, bi_ref, cnt_ref,
+        hw, d2, ids_ref[0, 0, 0], halves_ref[0], bd_ref, bi_ref, cnt_ref,
         steps=steps, ks=ks,
     )

@@ -13,15 +13,15 @@ from __future__ import annotations
 
 import jax
 
+from ..compat import make_mesh
+
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # every axis Auto — the default on all supported jax versions (the
-    # axis_types parameter does not exist on jax 0.4.x)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(multi_pod: bool = False):
@@ -29,4 +29,4 @@ def make_local_mesh(multi_pod: bool = False):
     n = len(jax.devices())
     shape = (1, 1, n) if multi_pod else (1, n)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

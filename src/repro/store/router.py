@@ -42,7 +42,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..checkpoint import Checkpointer
 from ..core import DBLSHParams
@@ -136,7 +136,12 @@ class ShardedCollection(CollectionLifecycle):
         search_policy=None,
         **derive_kw,
     ) -> "ShardedCollection":
-        data = jnp.asarray(data, jnp.float32)
+        # place each shard's rows straight onto its device: the global
+        # array never sits whole on one device
+        data = jax.device_put(
+            data if isinstance(data, jax.Array) else np.asarray(data),
+            NamedSharding(mesh, P(axis)),
+        ).astype(jnp.float32)
         n, d = data.shape
         pn = mesh.shape[axis]
         if params is None:
@@ -477,12 +482,7 @@ class ShardedCollection(CollectionLifecycle):
             new_gids[dst_off[r]:dst_off[r + 1]] = (
                 r * stride + np.arange(seg.shape[0])
             )
-        params = DBLSHParams.derive(
-            n=n_keep, d=p_old.d, c=p_old.c, w0=p_old.w0, t=p_old.t,
-            k=p_old.k, block_size=p_old.block_size,
-            inline_vectors=p_old.inline_vectors,
-            quant_dtype=p_old.quant_dtype,
-        )
+        params = p_old.rebuilt(n_keep)
         kw["key"], kb = jax.random.split(kw["key"])
         sharded = build_sharded(kb, jnp.asarray(padded), params, mesh,
                                 axis=axis, stride=stride)

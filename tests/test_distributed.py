@@ -5,14 +5,7 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
-
-# jax 0.4.x lowers axis_index over a partial-manual shard_map axis to a
-# PartitionId instruction its SPMD partitioner rejects; the PP schedule
-# needs exactly that (stage = axis_index('pod')). Fixed upstream in the
-# jax versions that ship jax.shard_map.
-_OLD_JAX = not hasattr(jax, "shard_map")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -20,11 +13,12 @@ SCRIPT_SHARDED_ANN = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from repro.core import DBLSHParams, brute_force, build, search_batch_fixed
 from repro.core.distributed import build_sharded, search_sharded
 from repro.data import make_clustered, normalize_scale
 
-mesh = jax.make_mesh((8,), ("data",))  # axis_types default to Auto
+mesh = make_mesh((8,), ("data",))
 key = jax.random.key(3)
 kd, kb = jax.random.split(key)
 allpts = make_clustered(kd, 4128, 24, n_clusters=16, spread=0.02)
@@ -64,12 +58,13 @@ SCRIPT_SHARDED_LIFECYCLE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from repro.core import DBLSHParams, brute_force
 from repro.data import make_clustered, normalize_scale
 from repro.store import (ShardedCollection, CompactionPolicy, StoreService,
                          open_collection, restore_collection)
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 key = jax.random.key(3)
 kd, kb = jax.random.split(key)
 allpts = make_clustered(kd, 4288, 24, n_clusters=16, spread=0.02)
@@ -184,7 +179,7 @@ np.testing.assert_array_equal(np.asarray(col.payload), np.asarray(col2.payload))
 # elastic restore: the same snapshot placed on HALF the shards — live
 # rows re-partition balanced over the new fleet, ids renumber, fitted
 # calibration drops, and identity carries through the payload tags
-mesh4 = jax.make_mesh((4,), ("data",))
+mesh4 = make_mesh((4,), ("data",))
 col4 = restore_collection(tmp, step, mesh=mesh4)
 n_live = col.live_count()
 assert col4.live_count() == n_live and col4.n == n_live
@@ -245,13 +240,14 @@ SCRIPT_SHARDED_EXPLAIN = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
+from repro.compat import make_mesh
 from repro.core import DBLSHParams
 from repro.core.distributed import build_sharded, search_sharded
 from repro.data import make_clustered, normalize_scale
 from repro.obs import Observability
 from repro.store import ShardedCollection, StoreService
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 key = jax.random.key(7)
 kd, kb = jax.random.split(key)
 allpts = make_clustered(kd, 4120, 24, n_clusters=8, spread=0.02)
@@ -302,6 +298,7 @@ SCRIPT_TRAIN_PARITY = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, SHAPES
 from repro.models.registry import build_model
@@ -325,7 +322,7 @@ for t in range(4):
     losses_ref.append(float(m["loss"]))
 
 # 2x4 mesh (data x model) distributed run
-mesh = jax.make_mesh((2, 4), ("data", "model"))  # Auto axes
+mesh = make_mesh((2, 4), ("data", "model"))
 with mesh:
     state_shapes = jax.eval_shape(lambda k: init_train_state(model, opt, k), jax.random.key(0))
     pspecs = rules.param_specs(state_shapes["params"], mesh, fsdp_min_size=1<<10)
@@ -351,6 +348,7 @@ SCRIPT_MOE_PARITY = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.models.registry import build_model
 
@@ -364,7 +362,7 @@ batch = {
 }
 loss_1dev = float(jax.jit(lambda p, b: model.loss(p, b)[0])(params, batch))
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))  # Auto axes
+mesh = make_mesh((2, 4), ("data", "model"))
 with mesh:
     loss_dist = float(
         jax.jit(lambda p, b: model.loss(p, b, mesh)[0])(params, batch)
@@ -424,6 +422,7 @@ SCRIPT_PP_PARITY = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.models.registry import build_model
 from repro.sharding.pp import pp_loss_fn
@@ -438,7 +437,7 @@ batch = {
 }
 ref = float(jax.jit(lambda p, b: model.loss(p, b)[0])(params, batch))
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))  # Auto axes
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 with mesh:
     pp = float(jax.jit(
         lambda p, b: pp_loss_fn(p, b, cfg, mesh, microbatches=4)
@@ -457,9 +456,5 @@ print("PP_PARITY_OK", ref, pp)
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(
-    _OLD_JAX, reason="partial-manual axis_index -> PartitionId, "
-    "unsupported by jax 0.4.x SPMD partitioning", strict=False,
-)
 def test_pp_parity_8dev():
     _run(SCRIPT_PP_PARITY, "PP_PARITY_OK")

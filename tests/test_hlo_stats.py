@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import make_mesh
 from repro.launch.hlo_stats import analyze
 
 
@@ -44,7 +45,7 @@ def test_collective_bytes_sharded_matmul():
     # here: spot-check that an explicit psum shows up.
     from repro.compat import shard_map
 
-    mesh = jax.make_mesh((1,), ("model",))  # axis_types default to Auto
+    mesh = make_mesh((1,), ("model",))
 
     def f(x):
         return shard_map(

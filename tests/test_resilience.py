@@ -43,8 +43,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.checkpoint import Checkpointer, CorruptSnapshot
+from repro.compat import make_mesh
 from repro.core import DBLSHParams
 from repro.data import make_clustered, normalize_scale
 from repro.obs.slo import SLOWatch
@@ -714,7 +716,7 @@ class TestStragglers:
         data, queries, kb = setup
         from repro.store import ShardedCollection
 
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         scol = ShardedCollection.create(
             "straggle", kb, data[:64], mesh, c=1.5, w0=3.6, t=8, k=10
         )

@@ -22,7 +22,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
+from repro.compat import make_mesh
 from repro.core import DBLSHParams, brute_force
 from repro.core.distributed import build_sharded, search_sharded
 from repro.store import (
@@ -52,9 +54,9 @@ def setup():
     return data, extra, queries, kb
 
 
-@pytest.fixture()
+@pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def _make(name, kb, data, mesh, **kw):

@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.compat import make_mesh
 from repro.core import brute_force, search_batch_fixed
 from repro.data import make_clustered, normalize_scale
 from repro.store import (
@@ -243,7 +244,7 @@ def test_sharded_collection_matches_local(setup):
     from repro.core import DBLSHParams, build
 
     data, queries, kb = setup
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params = DBLSHParams.derive(n=1200, d=16, c=1.5, w0=3.6, t=32, k=10)
     sc = ShardedCollection.create(
         "sh", kb, data, mesh, params=params, payload=np.arange(1200)
@@ -275,7 +276,7 @@ def test_sharded_collection_matches_local(setup):
 
 def test_open_collection_routing(setup):
     data, _, kb = setup
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     col = open_collection("a", kb, data, mesh=None, c=1.5, w0=3.6, t=32, k=10)
     assert isinstance(col, Collection)
     # a 1-device mesh can never fan out
@@ -366,7 +367,7 @@ def test_sharded_probe_stats_surface(setup):
     svc.stats() instead of being dropped at the boundary: on a 1-shard
     mesh the aggregates equal the local collection's own stats."""
     data, queries, kb = setup
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from repro.core import DBLSHParams, build
 
     params = DBLSHParams.derive(n=1200, d=16, c=1.5, w0=3.6, t=32, k=10)
@@ -462,10 +463,9 @@ def test_quant_sharded_roundtrip(setup, tmp_path):
     """Sharded quant collections: per-shard shortlist + re-rank, and the
     bit-identical restore path rebuilds per-shard quantized blocks (ids
     are shard-local — a global re-quantize would read the wrong rows)."""
-    from jax.sharding import Mesh
     data, queries, kb = setup
     ndev = len(jax.devices())
-    mesh = Mesh(np.array(jax.devices()).reshape(ndev), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     k = 10
     sc = ShardedCollection.create("q8s", kb, data, mesh, c=1.5, w0=3.6,
                                   t=32, k=k, quant_dtype="int8")

@@ -31,7 +31,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core import DBLSHParams, brute_force, search_batch_fixed
 from repro.data import make_clustered, normalize_scale
 from repro.store import (

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.data.pipeline import Prefetcher, SyntheticTokens, make_batch_fn
 from repro.models.registry import build_model
@@ -105,7 +106,7 @@ def test_elastic_restore_new_sharding(tmp_path):
     ck = Checkpointer(str(tmp_path))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     ck.save(3, tree, meta={"next_step": 3})
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = NamedSharding(mesh, P("data", None))
     got, _ = ck.restore(shardings=sh)
     assert got["w"].sharding == sh

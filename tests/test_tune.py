@@ -35,7 +35,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
+from repro.compat import make_mesh
 from repro.core import (
     DBLSHParams,
     Termination,
@@ -423,7 +425,7 @@ def test_sharded_termination_parity(setup):
     path exactly (the n-shard argument is monotonicity: a shard's local
     k-th ≥ the global k-th, so local C2 only fires later)."""
     data, queries, index = setup
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params = index.params
     # identical hash functions on both sides: build local + sharded from
     # the same key (the fixture's index used a different split)

@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import DBLSHParams, brute_force, build, search_batch_fixed
 from repro.core.updates import compact, delete, insert, live_count
