@@ -1,0 +1,14 @@
+#!/usr/bin/env python3
+"""Benchmark entry point: ``python3 bench/run.py --workload <cell> --seed <n>
+--seconds <s> --trace <0|1>``, from the root of a checkout.  See
+``bench/harness.py``."""
+
+import sys
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from bench.harness import main  # noqa: E402
+
+if __name__ == "__main__":
+    sys.exit(main())
