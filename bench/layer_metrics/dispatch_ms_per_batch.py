@@ -1,0 +1,15 @@
+"""Search step dispatch (``store/collection.py``, ``store/router.py``):
+host milliseconds per dispatched batch in the program's
+``issue.dispatch`` spans (the jitted search call, ``search_batch_fixed``
+or ``search_sharded``, until it returns device futures; a child of
+``batch.issue``), summed over the window and divided by the batches
+issued in it.  Moves ``qps`` in the closed loops.  A program without the
+span reads nothing."""
+
+
+def read(ctx):
+    spans = [s for s in ctx.spans if s.name == "issue.dispatch"]
+    batches = len(ctx.batches())
+    if not spans or not batches:
+        return None
+    return sum(s.dur for s in spans) * 1e3 / batches

@@ -213,17 +213,22 @@ def _bench_tenants(col, queries, *, batch_size: int, engine: str, k: int,
 
 def _overlap_visible(tracer: Tracer) -> bool:
     """True when the trace shows ring overlap *structurally*: some
-    batch's issue span sits inside an earlier batch's pending window, on
-    a different ring lane — the picture a Perfetto load should show."""
+    batch's issue span sits inside an earlier batch's in-flight window
+    (its issue's end to its completion's start, by ``seq``), on a
+    different ring lane — the picture a Perfetto load should show."""
     issues = [s for s in tracer.events if s.name == "batch.issue"]
-    pendings = [s for s in tracer.events if s.name == "batch.pending"]
-    for p in pendings:
+    completes = {s.args["seq"]: s for s in tracer.events
+                 if s.name == "batch.complete"}
+    for p in issues:
+        c = completes.get(p.args["seq"])
+        if c is None:
+            continue
         for i in issues:
             if (
-                i.args.get("seq", -1) > p.args.get("seq", -1)
+                i.args["seq"] > p.args["seq"]
                 and i.tid != p.tid
-                and p.ts <= i.ts
-                and i.ts + i.dur <= p.ts + p.dur
+                and p.ts + p.dur <= i.ts
+                and i.ts + i.dur <= c.ts
             ):
                 return True
     return False
@@ -253,8 +258,8 @@ def bench_obs(
     the enabled arm's metrics registry (JSON + Prometheus text), trace
     (JSONL + Perfetto ``trace_event`` timeline), and the explain arm's
     sampled-explains JSON next to ``out``, and verifies the timeline
-    actually shows ring overlap (batch N+1's issue span inside batch N's
-    pending window, one lane up).  With ``gate`` the ≤ ``max_overhead``
+    actually shows ring overlap (batch N+1's issue span between batch N's
+    issue and completion, one lane up).  With ``gate`` the ≤ ``max_overhead``
     overhead contract is a hard assert on the tracing *and* explain
     arms — the CI hook.
     """
@@ -340,7 +345,7 @@ def bench_obs(
     if stats["overlap_ratio"] > 0:
         assert overlap_ok, (
             "overlapped batches ran but the trace shows no nested "
-            "issue-inside-pending window"
+            "issue inside an earlier batch's in-flight window"
         )
 
     stem = out[:-5] if out.endswith(".json") else out

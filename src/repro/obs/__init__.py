@@ -4,14 +4,17 @@ Three pieces, one bundle (DESIGN.md §10):
 
 * ``trace``   — :class:`~repro.obs.trace.Tracer`: a bounded, typed span
   recorder.  Every submitted request leaves a trace across submit →
-  quota admission → queue wait → batch assembly → device dispatch →
-  in-flight ring pending window → host sync → cache put, and every
+  quota admission → queue wait → drain → cache lookup → batch assembly
+  → issue (query upload, jitted dispatch, payload gather) → completion
+  (device-to-host fetch, ticket fill) → ``request.done``, and every
   collection lifecycle mutation (add/remove/compact/calibrate/snapshot,
-  local and sharded) records a span on the same timeline.  Exports
-  JSONL and Chrome/Perfetto ``trace_event`` JSON; ``jax.named_scope``
-  labels on the jitted search stages plus a
-  ``jax.profiler.TraceAnnotation`` around dispatch let a device profile
-  correlate with the host spans by name.
+  local and sharded) records a span on the same timeline, with the
+  process's collector passes and compiles beside them.  Exports JSONL
+  and Chrome/Perfetto ``trace_event`` JSON; ``jax.named_scope`` labels
+  on the jitted search stages, a ``jax.profiler.TraceAnnotation`` around
+  dispatch, and ``clock.sync`` records (the tracer clock beside
+  ``time.time_ns()``) put a device profile and the host spans on one
+  timeline.
 
 * ``metrics`` — :class:`~repro.obs.metrics.MetricsRegistry`: counters,
   gauges, and fixed-bucket histograms (latency, queue depth, batch
@@ -29,9 +32,13 @@ Three pieces, one bundle (DESIGN.md §10):
 
 Overhead contract: tracing is **off by default** and every hot-path
 site guards on one attribute read; metrics are always on (plain dict
-arithmetic per request).  Enabled end-to-end, the stack stays within 5%
-of obs-off QPS with bit-equal results — gated by
-``benchmarks/store_throughput.py --obs``.
+arithmetic per request; XLA compiles are counted by a listener that runs
+only on a compile).  Results are bit-equal with tracing on or off.
+Tracing on (with ``jax.profiler`` running too) against off, same seeds,
+20 s closed-loop windows of 64 callers: on one TPU v5e serving 1M x 128
+in batches of 32, median QPS 1,486 against 1,493 (-0.4 %); on four,
+10M x 128 row-sharded, QPS -2.3 % and p99 latency +3.8 %, where the
+host's issue stage is on the critical path (PERF.md §6).
 
 Typical use::
 
@@ -67,7 +74,7 @@ from .metrics import (
     get_registry,
 )
 from .slo import BreachEvent, SLOWatch, expected_step_pmf
-from .trace import Span, Tracer, get_tracer
+from .trace import Span, Tracer, get_tracer, watch_compiles
 
 __all__ = [
     "BreachEvent",
@@ -108,6 +115,7 @@ class Observability:
                  exemplars: ExemplarReservoir | None = None,
                  explain_sample_rate: float = 0.0):
         self.registry = registry if registry is not None else MetricsRegistry()
+        watch_compiles(self.registry)
         self.tracer = tracer if tracer is not None else get_tracer()
         if trace:
             self.tracer.enable(sample_rate)

@@ -195,10 +195,9 @@ class Collection(CollectionLifecycle):
         ``quant_dtype`` and are a shortlist + exact fp32 re-rank, so the
         returned distances are always exact fp32.
         """
-        Q = jnp.atleast_2d(jnp.asarray(Q, jnp.float32))
-        self._count_queries(Q, rows)
-        return search_batch_fixed(
-            self.index, Q, k=k, r0=r0, steps=steps,
+        Q = self._upload(Q, rows)
+        return self._dispatch(
+            search_batch_fixed, self.index, Q, k=k, r0=r0, steps=steps,
             engine=engine or self.default_engine or "jnp",
             with_stats=with_stats, interpret=interpret, exact=exact,
             termination=termination, with_explain=with_explain,

@@ -156,6 +156,9 @@ class CollectionLifecycle:
                 payload.shape, self.id_space,
             )
         self.name = name
+        # the tracer this collection's lifecycle and dispatch spans land
+        # on: the process-global one until a StoreService attaches it
+        self.tracer = get_tracer()
         self.payload = payload
         self.policy = policy or CompactionPolicy()
         self._key = jax.random.key(0) if key is None else key
@@ -239,10 +242,11 @@ class CollectionLifecycle:
                     f"({payload.shape[0]}) != inserted points "
                     f"({points.shape[0]})"
                 )
-        # lifecycle mutations record on the process-global trace timeline
-        # (TID_LIFECYCLE lane), so a serving-stack trace shows mutations
-        # interleaved with the batches they invalidate
-        with get_tracer().span(
+        # lifecycle mutations record on the collection's tracer (the
+        # serving service's once attached; TID_LIFECYCLE lane), so a
+        # serving-stack trace shows mutations interleaved with the
+        # batches they invalidate
+        with self.tracer.span(
             "lifecycle.add", cat="lifecycle", collection=self.name,
             placement=self.placement, rows=int(points.shape[0]),
         ) as sp:
@@ -263,7 +267,7 @@ class CollectionLifecycle:
         when the policy fired — every outstanding id must be remapped
         through it — or None when no compaction happened."""
         ids = jnp.atleast_1d(jnp.asarray(ids, jnp.int32))
-        with get_tracer().span(
+        with self.tracer.span(
             "lifecycle.remove", cat="lifecycle", collection=self.name,
             placement=self.placement, rows=int(ids.shape[0]),
         ) as sp:
@@ -307,7 +311,7 @@ class CollectionLifecycle:
         K/L, which shifts the recall/cost curves) and re-fits it when
         the calibration queries were retained (``calibrate(...,
         retain=True)``)."""
-        with get_tracer().span(
+        with self.tracer.span(
             "lifecycle.compact", cat="lifecycle", collection=self.name,
             placement=self.placement, n_before=int(self.n),
         ) as sp:
@@ -367,7 +371,7 @@ class CollectionLifecycle:
         do not ride in snapshots — only the fitted table does."""
         kw = dict(k=k, r0=r0, steps_max=steps_max, engine=engine,
                   interpret=interpret, measure_ms=measure_ms)
-        with get_tracer().span(
+        with self.tracer.span(
             "lifecycle.calibrate", cat="lifecycle", collection=self.name,
             placement=self.placement, steps_max=steps_max,
         ):
@@ -390,8 +394,32 @@ class CollectionLifecycle:
         )
 
     # ------------------------------------------------------------------ reads
-    def _count_queries(self, Q, rows: int | None) -> None:
+    # A service's issue stage calls ``search`` inside
+    # ``tracer.children(issue_id, lane)``, so the two spans below record
+    # as children of its ``batch.issue``.
+    def _upload(self, Q, rows: int | None) -> jax.Array:
+        """``Q`` onto the device as a float32 matrix, counted in the
+        collection's stats (``rows`` real rows of a padded batch);
+        recorded as ``issue.upload``."""
+        tr = self.tracer
+        t0 = tr.clock() if tr.enabled else 0.0
+        Q = jnp.atleast_2d(jnp.asarray(Q, jnp.float32))
         self.stats.queries += int(Q.shape[0]) if rows is None else int(rows)
+        if tr.enabled:
+            tr.add_span("issue.upload", t0, tr.clock(), cat="batch",
+                        **tr.scope)
+        return Q
+
+    def _dispatch(self, search, *args, **kw):
+        """``search(*args, **kw)``, the placement's search program, until
+        it returns device futures; recorded as ``issue.dispatch``."""
+        tr = self.tracer
+        if not tr.enabled:
+            return search(*args, **kw)
+        t0 = tr.clock()
+        out = search(*args, **kw)
+        tr.add_span("issue.dispatch", t0, tr.clock(), cat="batch", **tr.scope)
+        return out
 
     def get_payload(self, ids):
         """Payload rows for returned neighbor ids.
@@ -415,7 +443,7 @@ class CollectionLifecycle:
         Defaults to one past the latest step already in ``directory`` so
         successive snapshots never overwrite each other (Checkpointer
         keeps the most recent few and GCs the rest)."""
-        with get_tracer().span(
+        with self.tracer.span(
             "lifecycle.snapshot", cat="lifecycle", collection=self.name,
             placement=self.placement,
         ) as sp:

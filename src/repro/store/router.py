@@ -313,17 +313,17 @@ class ShardedCollection(CollectionLifecycle):
         runs the quantized shortlist + exact re-rank locally, so the
         all_gather merge always compares fp32 distances."""
         del engine, interpret
-        Q = jnp.atleast_2d(jnp.asarray(Q, jnp.float32))
-        self._count_queries(Q, rows)
+        # onto one device; search_sharded replicates it over the mesh
+        Q = self._upload(Q, rows)
         k = k or self.sharded.index.params.k
         # shard.straggle: one slow shard stalls the all_gather merge —
         # injected here (a no-op without an installed FaultPlan) so the
         # service's EWMA straggler monitor sees it as a slow batch
         faults.fire("shard.straggle", collection=self.name, scale=steps)
-        return search_sharded(
-            self.sharded, Q, k=k, r0=r0, steps=steps, mesh=self.mesh,
-            with_stats=with_stats, exact=exact, termination=termination,
-            with_explain=with_explain, dtype=dtype,
+        return self._dispatch(
+            search_sharded, self.sharded, Q, k=k, r0=r0, steps=steps,
+            mesh=self.mesh, with_stats=with_stats, exact=exact,
+            termination=termination, with_explain=with_explain, dtype=dtype,
         )
 
     # ------------------------------------------------------------ persistence

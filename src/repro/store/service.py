@@ -75,7 +75,7 @@ from ..core.serve_search import PendingSearch, validate_engine
 from ..obs import Observability
 from ..obs.explain import TERM_CAUSE_NAMES, QueryExplain
 from ..obs.metrics import LATENCY_MS_BUCKETS, MetricsRegistry
-from ..obs.trace import TID_RING0, TID_SCHEDULER
+from ..obs.trace import TID_RING0, TID_SCHEDULER, get_tracer
 from ..resilience import faults
 from ..resilience.stragglers import StragglerMonitor
 from ..tune import planner as _planner
@@ -86,6 +86,7 @@ from ..tune.policy import (
     resolve_policy_with_source,
 )
 from .cache import CachedResult, QueryResultCache
+from .lifecycle import CollectionLifecycle
 
 __all__ = [
     "BrownoutShed",
@@ -594,8 +595,12 @@ class StoreService:
 
     # ----------------------------------------------------------------- admin
     def attach(self, collection) -> None:
-        """Register a Collection (or any search-compatible object)."""
+        """Register a Collection (or any search-compatible object).  A
+        store collection records its lifecycle and dispatch spans on this
+        service's tracer from here on."""
         self.collections[collection.name] = collection
+        if isinstance(collection, CollectionLifecycle):
+            collection.tracer = self.tracer
         self._queues.setdefault(collection.name, {})
         if collection.name not in self._stats:
             self._stats[collection.name] = _CollectionStats(
@@ -614,7 +619,9 @@ class StoreService:
             raise RuntimeError(f"collection {name!r} has pending requests")
         if any(b.name == name for b in self._inflight):
             raise RuntimeError(f"collection {name!r} has in-flight batches")
-        self.collections.pop(name, None)
+        col = self.collections.pop(name, None)
+        if isinstance(col, CollectionLifecycle) and col.tracer is self.tracer:
+            col.tracer = get_tracer()
         self._queues.pop(name, None)
         self._stats.pop(name, None)
         self._rr_pos.pop(name, None)
@@ -822,22 +829,28 @@ class StoreService:
                 timed_out = (now - oldest) * 1e3 >= self.max_wait_ms
                 if not (force or timed_out or total >= cap):
                     break
+                traced = self.tracer.enabled
+                t_d0 = self._clock() if traced else 0.0
                 reqs = self._drain_wrr(name, cap)
                 drained += len(reqs)
-                if self.tracer.enabled or \
-                        any(r.explain is not None for r in reqs):
+                if traced or any(r.explain is not None for r in reqs):
                     t_drain = self._clock()
                     for r in reqs:
                         if r.explain is not None:
                             r.explain.queue_wait_ms = \
                                 (t_drain - r.submitted) * 1e3
-                        if r.traced and self.tracer.enabled:
+                        if r.traced and traced:
                             self.tracer.add_span(
                                 "request.queue_wait", r.submitted, t_drain,
                                 cat="request", uid=r.uid, tenant=r.tenant,
                                 collection=name,
                             )
                 reqs = self._apply_deadlines(name, reqs)
+                if traced:
+                    self.tracer.add_span(
+                        "batch.drain", t_d0, self._clock(), collection=name,
+                        rows=len(reqs),
+                    )
                 misses = self._serve_cached(name, reqs)
                 if misses:
                     # one device program per (engine, plan, explain):
@@ -1032,6 +1045,8 @@ class StoreService:
                 if r.explain is not None:
                     r.explain.cache_outcome = "uncached"
             return reqs
+        traced = self.tracer.enabled
+        t_l0 = self._clock() if traced else 0.0
         misses = []
         for r in reqs:
             key = self._cache_key(name, version, r.query, r.engine, r.plan)
@@ -1057,14 +1072,29 @@ class StoreService:
             r.cached = True
             r.done = True
             if r.traced:
-                self.tracer.instant(
-                    "request.cache_hit", cat="request", t=now,
-                    uid=r.uid, collection=name,
-                )
+                self._trace_done(r, now, None)
             self._stats[name].record_hit(r, now)
             self._tstats(r.tenant).record_served(r, now)
             self.obs.exemplars.record(r.latency_ms, r.uid, name)
+        if traced:
+            self.tracer.add_span(
+                "cache.lookup", t_l0, self._clock(), cat="cache",
+                collection=name,
+                probes=sum(r.explain is None for r in reqs),
+                hits=len(reqs) - len(misses),
+            )
         return misses
+
+    def _trace_done(self, r: QueryRequest, now: float,
+                    batch_seq: int | None) -> None:
+        """The request's completion record: an instant, so that it never
+        covers (and names) a device-idle gap; the Perfetto export draws
+        it as the request's submit -> done slice."""
+        self.tracer.instant(
+            "request.done", cat="request", t=now, uid=r.uid,
+            submitted=r.submitted, latency_ms=r.latency_ms, cached=r.cached,
+            batch_seq=batch_seq,
+        )
 
     # ------------------------------------------------- issue / complete stages
     def _issue(self, name: str, reqs: list[QueryRequest],
@@ -1103,13 +1133,17 @@ class StoreService:
         self._batch_seq += 1
         # lane = ring slot this batch will occupy, so a Perfetto render
         # shows overlap directly: batch N+1's issue span sits one lane up,
-        # inside batch N's pending window
+        # between batch N's issue and its completion
         tid = TID_RING0 + len(self._inflight)
         t_i0 = self._clock()
         dispatch_ctx = (
             jax.profiler.TraceAnnotation(f"store.dispatch.{name}")
             if traced else contextlib.nullcontext()
         )
+        # the issue span is recorded once the dispatch returned; its
+        # children (upload and dispatch, recorded inside col.search, and
+        # the payload gather) name it as their parent
+        issue_sid = self.tracer.new_id() if traced else None
         # explain travels as an opt-in kwarg (like termination) so plain
         # attachables that predate it keep working on the default path
         explain_kw = {"with_explain": True} if with_explain else {}
@@ -1127,7 +1161,11 @@ class StoreService:
                 faults.fire("dispatch.delay_ms", collection=name,
                             scale=plan.steps)
                 faults.fire("dispatch.raise", collection=name, engine=engine)
-                with dispatch_ctx:
+                children = (
+                    self.tracer.children(issue_sid, tid) if traced
+                    else contextlib.nullcontext()
+                )
+                with dispatch_ctx, children:
                     out = col.search(
                         Q, k=self.default_k, r0=plan.r0, steps=plan.steps,
                         engine=engine, with_stats=True,
@@ -1141,8 +1179,14 @@ class StoreService:
                         dists, ids, stats = out
                     payload = None
                     if getattr(col, "payload", None) is not None:
+                        t_p0 = self._clock() if traced else 0.0
                         # async gather, same stream
                         payload = col.get_payload(ids[:m])
+                        if traced:
+                            self.tracer.add_span(
+                                "issue.payload", t_p0, self._clock(),
+                                cat="batch", parent=issue_sid, tid=tid,
+                            )
                 break
             except Exception as e:
                 attempts += 1
@@ -1170,7 +1214,7 @@ class StoreService:
             )
             self.tracer.add_span(
                 "batch.issue", t_i0, t_i1, cat="batch", tid=tid,
-                seq=seq, collection=name, rows=m, shape=shape,
+                sid=issue_sid, seq=seq, collection=name, rows=m, shape=shape,
                 engine=engine, overlapped=len(self._inflight) > 0,
             )
         batch = _InFlight(
@@ -1233,15 +1277,9 @@ class StoreService:
         if mon.record(batch.seq, max(now - batch.t_issued, 0.0)):
             self._stats[batch.name].record_straggler()
         if traced:
-            # pending window: issue handoff -> this host sync (batch N+1's
-            # issue span lands inside it when the ring overlapped)
+            complete_sid = self.tracer.new_id()
             self.tracer.add_span(
-                "batch.pending", batch.t_issued, t_c0, cat="batch",
-                tid=batch.tid, seq=batch.seq, collection=batch.name,
-            )
-            self.tracer.add_span(
-                "batch.complete", t_c0, now, cat="batch", tid=batch.tid,
-                seq=batch.seq, collection=batch.name, rows=len(batch.reqs),
+                "complete.fetch", t_c0, now, cat="batch", parent=complete_sid,
             )
         ex = batch.pending.explain
         if ex is not None:
@@ -1259,6 +1297,8 @@ class StoreService:
             if r.explain is not None and ex is not None:
                 self._fill_explain(r, batch, ex, j, now)
             r.done = True
+            if r.traced:
+                self._trace_done(r, now, batch.seq)
             if self.cache is not None and batch.version is not None:
                 # copies: r.dists/r.ids above are views of the same batch
                 # arrays, and callers own (and may mutate) their tickets
@@ -1280,14 +1320,24 @@ class StoreService:
             self.obs.exemplars.record(
                 r.latency_ms, r.uid, batch.name, r.explain
             )
-        if traced and self.cache is not None and batch.version is not None:
-            self.tracer.instant(
-                "cache.put", cat="cache", t=now, tid=batch.tid,
-                seq=batch.seq, collection=batch.name, entries=len(batch.reqs),
-            )
         self._stats[batch.name].record_batch(
             batch.reqs, batch.shape, now, overlapped=batch.overlapped
         )
+        if traced:
+            t_c1 = self._clock()
+            self.tracer.add_span(
+                "complete.tickets", now, t_c1, cat="batch",
+                parent=complete_sid,
+            )
+            m = len(batch.reqs)
+            # the counts the registry's candidate / step counters were
+            # just incremented by, over the batch's real rows
+            self.tracer.add_span(
+                "batch.complete", t_c0, t_c1, cat="batch", tid=batch.tid,
+                sid=complete_sid, seq=batch.seq, collection=batch.name,
+                rows=m, candidates=int(cands[:m].sum()),
+                steps=int(steps_taken[:m].sum()),
+            )
         self._g_ring.set(len(self._inflight))  # callers popleft before calling
 
     def _fill_explain(self, r: QueryRequest, batch: _InFlight,

@@ -24,8 +24,10 @@ Six suites over the observability stack:
   driven by the fake clock.
 """
 
+import gc
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -45,7 +47,13 @@ from repro.obs import (
     expected_step_pmf,
     get_tracer,
 )
-from repro.obs.trace import TID_LIFECYCLE, TID_RING0, TID_SCHEDULER
+from repro.obs import trace as trace_mod
+from repro.obs.trace import (
+    TID_LIFECYCLE,
+    TID_RING0,
+    TID_SCHEDULER,
+    wall_offset_ns,
+)
 from repro.store import (
     Collection,
     DeadlineExceeded,
@@ -99,7 +107,26 @@ def _global_tracer_clean():
     tr.clear()
 
 
+@pytest.fixture
+def no_collector():
+    """An enabled tracer records every Python collector pass: the unit
+    tests that count records run with automatic passes off."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+def _own(tr):
+    """The spans a test recorded itself: without the tracer's
+    ``clock.sync`` instants and process records (``host.gc``,
+    ``jit.compile``)."""
+    return [s for s in tr.events if s.cat not in ("clock", "runtime")]
+
+
 # --------------------------------------------------------------- tracer units
+@pytest.mark.usefixtures("no_collector")
 class TestTracer:
     def test_disabled_records_nothing(self):
         clk = FakeClock()
@@ -117,7 +144,7 @@ class TestTracer:
         tr.add_span("late", 5.0, 7.0, tid=TID_RING0 + 1, seq=2)
         tr.add_span("early", 1.0, 6.0, tid=TID_RING0, seq=1)
         # export order is by start time, not insertion order
-        names = [s.name for s in sorted(tr.events, key=lambda s: s.ts)]
+        names = [s.name for s in sorted(_own(tr), key=lambda s: s.ts)]
         assert names == ["early", "late"]
         early = next(s for s in tr.events if s.name == "early")
         assert early.dur == pytest.approx(5.0)
@@ -155,6 +182,107 @@ class TestTracer:
             tr.add_span(f"s{i}", float(i), float(i) + 0.5)
         assert len(tr.events) == 4
         assert [s.name for s in tr.events] == ["s6", "s7", "s8", "s9"]
+
+    def test_parents_and_returned_ids(self):
+        clk = FakeClock()
+        tr = Tracer(enabled=True, clock=clk)
+        # a parent recorded after its children reserves its id first
+        parent = tr.new_id()
+        child = tr.add_span("child", 1.0, 2.0, parent=parent, tid=TID_RING0)
+        assert tr.add_span("parent", 0.5, 3.0, sid=parent,
+                           tid=TID_RING0) == parent
+        spans = {s.name: s for s in tr.events}
+        assert spans["child"].sid == child != parent
+        assert spans["child"].parent == parent
+        assert spans["parent"].sid == parent and spans["parent"].parent is None
+        # code called inside children() records **scope as the child
+        assert tr.scope == {}
+        with tr.children(parent, TID_RING0 + 1):
+            tr.add_span("scoped", 1.0, 1.5, **tr.scope)
+        assert tr.scope == {}
+        scoped = next(s for s in tr.events if s.name == "scoped")
+        assert (scoped.parent, scoped.tid) == (parent, TID_RING0 + 1)
+        assert Tracer().add_span("off", 0.0, 1.0) is None
+
+    def test_clock_sync_cadence(self):
+        clk = FakeClock(100.0)
+        tr = Tracer(enabled=True, clock=clk)
+
+        def syncs():
+            return [s for s in tr.events if s.name == "clock.sync"]
+
+        assert len(syncs()) == 1 and syncs()[0].ts == 100.0  # tracing started
+        clk.advance(0.6)
+        tr.add_span("a", 100.0, 100.6)
+        assert len(syncs()) == 1                  # under a second since
+        clk.advance(0.4)
+        tr.add_span("b", 100.6, 101.0)
+        assert len(syncs()) == 2 and syncs()[-1].ts == 101.0
+        clk.advance(0.5)
+        tr.instant("c")
+        tr.add_span("d", 101.0, 101.5)
+        assert len(syncs()) == 2
+        tr.clear()
+        assert [s.name for s in tr.events] == ["clock.sync"]
+        tr.disable()
+        tr.clear()
+        assert not tr.events
+        tr.enable()
+        assert [s.name for s in tr.events] == ["clock.sync"]
+        tr.disable()
+
+    def test_clock_sync_maps_onto_time_ns(self):
+        tr = Tracer(enabled=True)
+        try:
+            offset = wall_offset_ns(tr.events)
+            for _ in range(3):
+                mapped = tr.clock() * 1e9 + offset
+                assert abs(time.time_ns() - mapped) < 1e6
+                time.sleep(0.01)
+            assert wall_offset_ns([]) is None
+        finally:
+            tr.disable()
+
+    def test_gc_hook_follows_enable(self):
+        tr = Tracer()
+        assert tr not in trace_mod._ENABLED
+        tr.enable()
+        try:
+            assert trace_mod._gc_callback in gc.callbacks
+            assert tr in trace_mod._ENABLED
+            gc.collect()
+            passes = [s for s in tr.events if s.name == "host.gc"]
+            assert passes and passes[-1].args["generation"] == 2
+            assert passes[-1].args["collected"] >= 0
+            assert passes[-1].tid == TID_SCHEDULER and passes[-1].dur > 0
+        finally:
+            tr.disable()
+        assert tr not in trace_mod._ENABLED
+        if not trace_mod._ENABLED:  # no tracer enabled: nothing installed
+            assert trace_mod._gc_callback not in gc.callbacks
+        n = len(tr.events)
+        gc.collect()
+        assert len(tr.events) == n
+
+    def test_compile_counter_and_span_on_fresh_jit(self):
+        obs = Observability(tracer=Tracer(enabled=True))
+        try:
+            reg = obs.registry
+            compiles = reg.get("repro_jit_compiles_total")
+            seconds = reg.get("repro_jit_compile_seconds_total")
+            x = jax.numpy.arange(13.0).block_until_ready()
+            n0, s0 = compiles.value(), seconds.value()
+            f = jax.jit(lambda v: v * 3.25 + 7.0)
+            f(x).block_until_ready()
+            assert compiles.value() == n0 + 1
+            assert seconds.value() > s0
+            spans = [s for s in obs.tracer.events if s.name == "jit.compile"]
+            assert spans and spans[-1].dur > 0
+            assert spans[-1].tid == TID_SCHEDULER
+            f(x).block_until_ready()  # cached: no compile
+            assert compiles.value() == n0 + 1
+        finally:
+            obs.tracer.disable()
 
 
 # -------------------------------------------------------------- metrics units
@@ -216,6 +344,7 @@ class TestMetrics:
 
 
 # ------------------------------------------------------------------- exports
+@pytest.mark.usefixtures("no_collector")
 class TestExports:
     def test_prometheus_text(self):
         reg = MetricsRegistry()
@@ -254,11 +383,13 @@ class TestExports:
         tr.add_span("b", 2.0, 3.0, cat="batch", seq=1)
         tr.add_span("a", 0.0, 1.0, cat="batch", seq=0)
         path = tmp_path / "spans.jsonl"
-        assert tr.export_jsonl(str(path)) == 2
+        # the clock.sync recorded when tracing started carries the offset
+        assert tr.export_jsonl(str(path)) == 3
         rows = [json.loads(l) for l in path.read_text().splitlines()]
-        assert [r["name"] for r in rows] == ["a", "b"]  # time-sorted
-        assert rows[0]["dur"] == pytest.approx(1.0)
-        assert rows[1]["args"]["seq"] == 1
+        assert [r["name"] for r in rows] == ["clock.sync", "a", "b"]  # time-sorted
+        assert rows[0]["args"]["wall_ns"] > 0
+        assert rows[1]["dur"] == pytest.approx(1.0)
+        assert rows[2]["args"]["seq"] == 1
 
     def test_perfetto_timeline(self, tmp_path):
         clk = FakeClock()
@@ -267,9 +398,9 @@ class TestExports:
         # lane; one instant
         tr.add_span("request.queue_wait", 0.0, 2.0, cat="request", uid=1)
         tr.add_span("request.queue_wait", 1.0, 3.0, cat="request", uid=2)
-        tr.add_span("batch.pending", 1.0, 2.5, cat="batch",
+        tr.add_span("batch.issue", 1.0, 2.5, cat="batch",
                     tid=TID_RING0, seq=0)
-        tr.instant("cache.put", t=2.5, entries=4)
+        tr.instant("quota.reject", t=2.5, tenant="t")
         path = tmp_path / "trace.json"
         tr.export_perfetto(str(path))
         blob = json.loads(path.read_text())
@@ -288,7 +419,10 @@ class TestExports:
         # the batch span is a complete X slice with µs duration
         x = next(e for e in ev if e["ph"] == "X")
         assert x["dur"] == pytest.approx(1.5 * 1e6)
-        assert any(e["ph"] == "i" and e["name"] == "cache.put" for e in ev)
+        assert any(e["ph"] == "i" and e["name"] == "quota.reject" for e in ev)
+        sync = next(s for s in tr.events if s.name == "clock.sync")
+        assert blob["otherData"]["wall_minus_clock_ns"] == \
+            sync.args["wall_ns"] - sync.ts * 1e9
 
 
 # ------------------------------------------------------- service integration
@@ -395,14 +529,101 @@ class TestServiceIntegration:
         svc = _service(col, clk, obs=obs)
         svc.serve("obscol", queries[:4])
         names = {s.name for s in obs.tracer.events}
-        assert {"request.queue_wait", "batch.assemble", "batch.issue",
-                "batch.pending", "batch.complete"} <= names
+        assert {"request.queue_wait", "batch.drain", "cache.lookup",
+                "batch.assemble", "batch.issue", "issue.upload",
+                "issue.dispatch", "batch.complete", "complete.fetch",
+                "complete.tickets", "request.done"} <= names
+        # the waiting span and the per-batch / per-hit instants are gone
+        assert not names & {"batch.pending", "cache.put", "request.cache_hit"}
         issue = next(s for s in obs.tracer.events if s.name == "batch.issue")
         assert issue.tid >= TID_RING0
         assemble = next(
             s for s in obs.tracer.events if s.name == "batch.assemble"
         )
         assert assemble.tid == TID_SCHEDULER
+        by_sid = {s.sid: s for s in obs.tracer.events}
+        for name in ("issue.upload", "issue.dispatch"):
+            child = next(s for s in obs.tracer.events if s.name == name)
+            assert by_sid[child.parent] is issue and child.tid == issue.tid
+        complete = next(
+            s for s in obs.tracer.events if s.name == "batch.complete"
+        )
+        for name in ("complete.fetch", "complete.tickets"):
+            child = next(s for s in obs.tracer.events if s.name == name)
+            assert by_sid[child.parent] is complete
+            assert child.tid == TID_SCHEDULER
+        for name in ("batch.drain", "cache.lookup"):
+            leaf = next(s for s in obs.tracer.events if s.name == name)
+            assert leaf.tid == TID_SCHEDULER and leaf.parent is None
+        lookup = next(s for s in obs.tracer.events if s.name == "cache.lookup")
+        assert (lookup.args["probes"], lookup.args["hits"]) == (4, 0)
+
+    def test_request_done_and_cache_hits(self, setup, col, tmp_path):
+        _, queries, _ = setup
+        clk = FakeClock()
+        obs = Observability(tracer=Tracer(enabled=True, clock=clk))
+        svc = _service(col, clk, obs=obs, max_wait_ms=1e9)
+        for q in queries[:3]:
+            svc.submit("obscol", q)
+        clk.advance(0.25)
+        svc.flush()
+        clk.advance(0.5)
+        _, _, hits = svc.serve("obscol", queries[:3])  # all from the cache
+        done = [s for s in obs.tracer.events if s.name == "request.done"]
+        assert len(done) == 6 and all(s.dur == 0 and s.ph == "i" for s in done)
+        assert [s.args["cached"] for s in done] == [False] * 3 + [True] * 3
+        seq = next(s for s in obs.tracer.events
+                   if s.name == "batch.complete").args["seq"]
+        assert all(s.args["batch_seq"] == seq for s in done[:3])
+        assert all(s.args["batch_seq"] is None for s in done[3:])
+        assert done[0].args["latency_ms"] == pytest.approx(250.0)
+        assert [s.args["uid"] for s in done[3:]] == [r.uid for r in hits]
+        lookups = [s for s in obs.tracer.events if s.name == "cache.lookup"]
+        assert (lookups[-1].args["probes"], lookups[-1].args["hits"]) == (3, 3)
+        # exported as the request's async submit -> done pair
+        path = tmp_path / "trace.json"
+        obs.tracer.export_perfetto(str(path))
+        ev = [e for e in json.loads(path.read_text())["traceEvents"]
+              if e.get("name") == "request.done"]
+        first = str(done[0].args["uid"])
+        b = next(e for e in ev if e["ph"] == "b" and e["id"] == first)
+        e = next(e for e in ev if e["ph"] == "e" and e["id"] == first)
+        assert b["ts"] == pytest.approx(0.0)
+        assert e["ts"] == pytest.approx(0.25 * 1e6)
+        assert not [e for e in ev if e["ph"] == "i"]
+
+    def test_batch_complete_counts_candidates(self, setup, col):
+        _, queries, _ = setup
+        clk = FakeClock()
+        obs = Observability(tracer=Tracer(enabled=True, clock=clk))
+        svc = _service(col, clk, obs=obs, cache_size=0)
+        _, _, reqs = svc.serve("obscol", queries[:11])
+        completes = [s for s in obs.tracer.events
+                     if s.name == "batch.complete"]
+        assert sum(s.args["rows"] for s in completes) == 11
+        assert sum(s.args["candidates"] for s in completes) == \
+            sum(r.candidates for r in reqs) > 0
+        assert sum(s.args["steps"] for s in completes) == \
+            sum(r.radius_steps for r in reqs)
+        assert sum(s.args["candidates"] for s in completes) == \
+            svc.registry.get("repro_store_candidates_total").value(
+                collection="obscol")
+
+    def test_payload_gather_is_an_issue_child(self, setup):
+        data, queries, kb = setup
+        params = DBLSHParams.derive(
+            n=256, d=12, c=1.5, w0=3.6, t=12, k=8, inline_vectors=True
+        )
+        pcol = Collection.create("paycol", kb, data, params=params,
+                                 payload=np.arange(256, dtype=np.int32))
+        clk = FakeClock()
+        obs = Observability(tracer=Tracer(enabled=True, clock=clk))
+        svc = _service(pcol, clk, obs=obs)
+        svc.serve("paycol", queries[:4])
+        issue = next(s for s in obs.tracer.events if s.name == "batch.issue")
+        gather = next(s for s in obs.tracer.events
+                      if s.name == "issue.payload")
+        assert gather.parent == issue.sid and gather.tid == issue.tid
 
     def test_lifecycle_spans_on_global_tracer(self, setup):
         data, _, kb = setup
@@ -425,6 +646,25 @@ class TestServiceIntegration:
         assert add.tid == TID_LIFECYCLE
         assert add.args["rows"] == 3 and "version" in add.args
         assert by_name["lifecycle.compact"].args["n_after"] > 0
+        # attached to a service: its lifecycle spans land on the
+        # service's tracer, not on the global one
+        tr.clear()
+        tr.enable()
+        own = Tracer(enabled=True)
+        svc = StoreService(batch_shapes=(1,), default_k=8,
+                           obs=Observability(tracer=own))
+        svc.attach(c2)
+        try:
+            c2.add(data[:2] + 0.25)
+            assert "lifecycle.add" in {s.name for s in own.events}
+            assert "lifecycle.add" not in {s.name for s in tr.events}
+            # dropped: back on the global tracer
+            svc.drop_collection("mut")
+            c2.add(data[:2] + 0.75)
+            assert "lifecycle.add" in {s.name for s in tr.events}
+        finally:
+            tr.disable()
+            own.disable()
 
 
 # ---------------------------------------------------------------- bit-equality
@@ -444,10 +684,20 @@ def test_obs_on_off_bit_equal(setup, col, engine):
         d, i, _ = svc.serve("obscol", queries[:8])
         return np.asarray(d), np.asarray(i)
 
-    d_off, i_off = run(None)
+    off = Observability(tracer=Tracer())
+    d_off, i_off = run(off)
+    # tracing off: not one record, the process records included
+    gc.collect()
+    assert not off.tracer.events
     obs = Observability(tracer=Tracer(enabled=True))
-    d_on, i_on = run(obs)
-    assert obs.tracer.events  # it really traced
+    try:
+        d_on, i_on = run(obs)
+    finally:
+        obs.tracer.disable()
+    names = {s.name for s in obs.tracer.events}
+    assert {"clock.sync", "batch.drain", "cache.lookup", "issue.upload",
+            "issue.dispatch", "complete.fetch", "complete.tickets",
+            "request.done"} <= names  # it really traced
     np.testing.assert_array_equal(d_off, d_on)
     np.testing.assert_array_equal(i_off, i_on)
 
