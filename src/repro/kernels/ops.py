@@ -359,6 +359,13 @@ def fused_window_search(blk_idx, halves, proj_blocks, x_blocks, norm_blocks,
             pl.BlockSpec((1, steps, ks), lambda qi, s, blk: (qi, 0, 0)),
             pl.BlockSpec((1, 1, steps), lambda qi, s, blk: (qi, 0, 0)),
         ],
+        # the query's slot rows (d², ids, bins): selected once per bin
+        # at its last slot step
+        scratch_shapes=[
+            pltpu.VMEM((S, B), jnp.float32),
+            pltpu.VMEM((S, B), jnp.int32),
+            pltpu.VMEM((S, B), jnp.int32),
+        ],
     )
     bd, bi, cnt = pl.pallas_call(
         kern,

@@ -55,8 +55,14 @@ counter (the ``with_stats``/C1 feed).  The caller recovers exact
 per-step merge semantics by prefix-merging the bins (windows nest, so
 bin j IS the step-j delta):
 
-* ``fused_cand_kernel``   — pre-gathered candidates, grid (Q, L, Ct/TC).
-* ``fused_window_kernel`` — scalar-prefetch block DMA, grid (Q, S).
+* ``fused_cand_kernel``   — pre-gathered candidates, grid (Q, L, Ct/TC);
+  merges each candidate tile into the bins as it streams (``merge_topk``
+  per bin and tile).
+* ``fused_window_kernel`` — scalar-prefetch block DMA, grid (Q, S); each
+  slot step only writes its (d², id, bin) row into the query's (S, B)
+  VMEM scratch, and the last slot step selects every bin's top-ks once
+  over the whole tile (``_select_bins``): one ks-round selection a
+  query, not one a bin and slot.
 
 Both take a ``mode`` in {'exact', 'norm', 'bf16', 'int8'}: the
 quantized modes compute the dot against quantized blocks (per-slot
@@ -67,7 +73,9 @@ VMEM budget (per grid step, fp32): TILE_C*(K + d + 1) + 2k floats.
 With TILE_C = 256, K = 12, d = 128, k = 50: ~145 KiB — comfortably
 inside the ~16 MiB v5e VMEM; TILE_C is raised by ops.py when d is small.
 The fused accumulators add steps*(2*ks + 1) words — 2.6 KiB at
-steps = 8, ks = 40 (see DESIGN.md §13 for the full table).
+steps = 8, ks = 40 — and the window variant's scratch 3*S*B words,
+three (32, 128)-padded tiles of 16 KiB at S = 25, B = 64 (see
+DESIGN.md §13 for the full table).
 """
 
 from __future__ import annotations
@@ -295,22 +303,17 @@ def _slot_d2(x, q, nrm, q2, *, mode: str, xscale=None, qscale=None):
     return jnp.maximum(nrm - 2.0 * (xscale * qscale * dot) + q2, 0.0)
 
 
-def _fused_slot_update(hw, d2, ids, halves, bd_ref, bi_ref, cnt_ref, *,
-                       steps: int, ks: int):
-    """Fold one slot's candidates into the per-step bin accumulators.
+def _bin_and_count(hw, halves, cnt_ref, *, steps: int):
+    """Each candidate's schedule *bin*, with the admitted slots counted.
 
-    Each candidate belongs to exactly one schedule *bin*: the first step
-    whose window admits it, ``binid = #{j : hw > w_j/2}`` (``steps`` =
-    never admitted; hw = +inf slots land there).  Windows nest, so the
-    step-j delta slice of the radius schedule is exactly bin j — the
-    epilogue recovers the per-step merge semantics by prefix-merging the
-    bins.  ``cnt`` accumulates admitted candidate slots per bin; its
-    cumulative sum equals the C1 admission count ``#{hw <= w_j/2}``.
-
-    ``bd/bi`` are (1, steps, ks) and ``cnt`` (1, 1, steps) accumulators
-    revisited across the slot axis of the grid; ``merge_topk``'s
-    drop-equal-(dist, id) step dedups cross-table duplicates within a bin
-    exactly as the flat merge does.
+    A candidate belongs to exactly one bin: the first step whose window
+    admits it, ``binid = #{j : hw > w_j/2}`` (``steps`` = never admitted;
+    hw = +inf slots land there).  Windows nest, so the step-j delta slice
+    of the radius schedule is exactly bin j — the epilogue recovers the
+    per-step merge semantics by prefix-merging the bins.  ``cnt`` (1, 1,
+    steps), revisited across the grid's slot axis, accumulates admitted
+    slots per bin; its cumulative sum equals the C1 admission count
+    ``#{hw <= w_j/2}``.
     """
     c = hw.shape[0]
     binid = jnp.sum((hw[None, :] > halves[:, None]).astype(jnp.int32), axis=0)
@@ -318,6 +321,18 @@ def _fused_slot_update(hw, d2, ids, halves, bd_ref, bi_ref, cnt_ref, *,
     stepv = jax.lax.broadcasted_iota(jnp.int32, (steps, c), 0)
     hits = binid[None, :] == stepv  # (steps, C)
     cnt_ref[0, 0] = cnt_ref[0, 0] + jnp.sum(hits.astype(jnp.int32), axis=1)
+    return binid
+
+
+def _fused_slot_update(hw, d2, ids, halves, bd_ref, bi_ref, cnt_ref, *,
+                       steps: int, ks: int):
+    """Fold one tile's candidates into the per-step bin accumulators.
+
+    ``bd/bi`` are (1, steps, ks) accumulators revisited across the
+    tiles of the grid; ``merge_topk``'s drop-equal-(dist, id) step dedups
+    cross-table duplicates within a bin exactly as the flat merge does.
+    """
+    binid = _bin_and_count(hw, halves, cnt_ref, steps=steps)
     for j in range(steps):
         m = binid == j
 
@@ -330,38 +345,81 @@ def _fused_slot_update(hw, d2, ids, halves, bd_ref, bi_ref, cnt_ref, *,
             bi_ref[0, j] = ni
 
 
+def _select_bins(d2, ids, binid, *, steps: int, ks: int):
+    """Per-bin top-ks of one query's whole (S, B) candidate tile.
+
+    ``d2`` (S, B) f32 squared distances, ``ids`` (S, B) i32 and ``binid``
+    (S, B) i32 first admitting steps (``steps`` = never admitted).  The
+    bins are reduced side by side as a (steps, S, B) array: one ks-round
+    min-select with ``merge_topk``'s rule — smallest d², ties to the
+    smallest id, every entry equal to the chosen (d², id) dropped (the
+    cross-table duplicates) — so the result is the ks lexicographically
+    smallest distinct pairs of each bin, ``fused_search_ref``'s contract.
+    Returns (steps, 1, ks) distances (+inf padded) and ids (IMAX padded).
+    """
+    shape = (steps,) + d2.shape
+    stepv = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cd = jnp.where(binid[None] == stepv, d2[None], _INF)
+    ci = jnp.broadcast_to(ids[None], shape)
+    rank = jax.lax.broadcasted_iota(jnp.int32, (steps, 1, ks), 2)
+
+    def body(r, carry):
+        cd, nd, ni = carry
+        m = jnp.min(jnp.min(cd, axis=2, keepdims=True), axis=1, keepdims=True)
+        eq = cd == m
+        sel = jnp.min(
+            jnp.min(jnp.where(eq, ci, _IMAX), axis=2, keepdims=True),
+            axis=1, keepdims=True,
+        )
+        oh = rank == r
+        nd = jnp.where(oh, m, nd)
+        ni = jnp.where(oh & (m < _INF), sel, ni)
+        cd = jnp.where(eq & (ci == sel), _INF, cd)
+        return cd, nd, ni
+
+    init = (cd, jnp.full((steps, 1, ks), _INF, jnp.float32),
+            jnp.full((steps, 1, ks), _IMAX, jnp.int32))
+    _, nd, ni = jax.lax.fori_loop(0, ks, body, init)
+    return nd, ni
+
+
 def fused_window_kernel(*refs, lnb: int, steps: int, ks: int, mode: str):
     """One-pass fused search over an 'inline' layout: select-slot DMA +
     halfwidth + distance + schedule binning + per-bin top-ks, one kernel.
 
     Grid (Q, S).  Scalar-prefetch block DMA exactly as
-    ``window_dist_kernel``; candidates never reach HBM — the only
-    outputs are the (1, steps, ks) bin accumulators and the (1, 1, steps)
-    admitted-slot counters, revisited across the S slot steps.
+    ``window_dist_kernel``; candidates never reach HBM.  Each slot step
+    writes its (d², id, bin) row into the query's (S, B) VMEM scratch
+    tiles and adds its admitted slots to ``cnt``; the last slot step
+    selects every bin's top-ks once over the whole tile
+    (``_select_bins``) and writes the (1, steps, ks) bins.
 
     Blocks: g (1,1,1,K) of the slot's table, q (1,1,d), q2 (1,1,1); proj
     (1,B,K) and vec (1,B,d) of the selected block; norms and ids arrive
     as the aligned (8,B) row tile holding the block, of which row
     ``blk % 8`` is the block's.  Quantized modes take two extra refs: the
     per-query quant scale (qs, (1,1,1)) after q2 and the per-slot dequant
-    scales (scl, an (8,B) row tile) after ids."""
+    scales (scl, an (8,B) row tile) after ids.  Scratch: d², ids and bin
+    ids, (S, B) each, persisting across grid steps."""
     quant = mode in ("bf16", "int8")
     if quant:
         (blk_ref, halves_ref, g_ref, q_ref, q2_ref, qs_ref,
          proj_ref, vec_ref, nrm_ref, ids_ref, scl_ref,
-         bd_ref, bi_ref, cnt_ref) = refs
+         bd_ref, bi_ref, cnt_ref, d2_scr, id_scr, bin_scr) = refs
     else:
         (blk_ref, halves_ref, g_ref, q_ref, q2_ref,
          proj_ref, vec_ref, nrm_ref, ids_ref,
-         bd_ref, bi_ref, cnt_ref) = refs
+         bd_ref, bi_ref, cnt_ref, d2_scr, id_scr, bin_scr) = refs
     qi = pl.program_id(0)
     s = pl.program_id(1)
+    S, B = d2_scr.shape
 
+    # the scratch carries the previous query's rows: an invalid slot
+    # skips its compute, so its row must read "never admitted"
     @pl.when(s == 0)
     def _init():
-        bd_ref[...] = jnp.full_like(bd_ref, _INF)
-        bi_ref[...] = jnp.full_like(bi_ref, _IMAX)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        bin_scr[...] = jnp.full_like(bin_scr, steps)
 
     blk = blk_ref[qi, s]
 
@@ -377,10 +435,20 @@ def fused_window_kernel(*refs, lnb: int, steps: int, ks: int, mode: str):
             xscale=scl_ref[row, :][0] if quant else None,
             qscale=qs_ref[0, 0, 0] if quant else None,
         )
-        _fused_slot_update(
-            hw, d2, ids_ref[row, :][0], halves_ref[0], bd_ref, bi_ref,
-            cnt_ref, steps=steps, ks=ks,
+        binid = _bin_and_count(hw, halves_ref[0], cnt_ref, steps=steps)
+        slot = pl.ds(s, 1)
+        d2_scr[slot, :] = d2.reshape(1, B)
+        id_scr[slot, :] = ids_ref[row, :]
+        bin_scr[slot, :] = binid.reshape(1, B)
+
+    @pl.when(s == S - 1)
+    def _select():
+        nd, ni = _select_bins(
+            d2_scr[...], id_scr[...], bin_scr[...], steps=steps, ks=ks
         )
+        for j in range(steps):
+            bd_ref[0, pl.ds(j, 1), :] = nd[j]
+            bi_ref[0, pl.ds(j, 1), :] = ni[j]
 
 
 def fused_cand_kernel(*refs, steps: int, ks: int, mode: str):

@@ -62,17 +62,18 @@ def _check(compiled, *, kernel: bool):
 
 
 @pytest.mark.parametrize("Qn", BATCHES)
-@pytest.mark.parametrize("mode", ["norm", "exact", "int8"])
+@pytest.mark.parametrize("mode", ["norm", "exact", "int8", "bf16"])
 def test_fused_window_search_compiles(one_chip, mode, Qn):
-    ks = 4 * k if mode == "int8" else k
-    x_dtype = jnp.int8 if mode == "int8" else jnp.float32
+    quant = mode in ("int8", "bf16")
+    ks = 4 * k if quant else k
+    x_dtype = {"int8": jnp.int8, "bf16": jnp.bfloat16}.get(mode, jnp.float32)
     S = lambda shape, dt=jnp.float32: _sds(one_chip, shape, dt)  # noqa: E731
     args = [
         S((Qn, L * M), jnp.int32), S((STEPS,)), S((LNB, B, K)),
         S((LNB, B, D), x_dtype), S((LNB, B)), S((LNB, B), jnp.int32),
         S((Qn, L, K)), S((Qn, D)),
     ]
-    scale = S((LNB, B)) if mode == "int8" else None
+    scale = S((LNB, B)) if quant else None
 
     def f(*a, x_scale=None):
         return kernels.fused_window_search(

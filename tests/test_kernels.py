@@ -278,6 +278,109 @@ def test_fused_cand_search_matches_ref(Q, L, Ct, K, d, ks, steps, mode):
     _assert_bins_equal(got, ref, n)
 
 
+def _int8_pool(q, qb, qsc, norm_blocks, blk_idx):
+    """The int8 kernel's (Q, S*B) d² pool: the same quantized dot,
+    dequantized in the kernel's order."""
+    qv, qqs = _quantize_query(q, "int8")
+    xq = jnp.take(qb, blk_idx, axis=0, mode="fill", fill_value=0)
+    xs = jnp.take(qsc, blk_idx, axis=0, mode="fill", fill_value=1.0)
+    nrm = jnp.take(norm_blocks, blk_idx, axis=0, mode="fill", fill_value=jnp.inf)
+    idot = jnp.einsum("qsbd,qd->qsb", xq.astype(jnp.int32),
+                      qv.astype(jnp.int32)).astype(jnp.float32)
+    q2 = jnp.sum(jnp.square(q), axis=-1)
+    return jnp.maximum(
+        nrm - 2.0 * (xs * qqs[:, :, None] * idot) + q2[:, None, None], 0.0
+    ).reshape(q.shape[0], -1)
+
+
+def _mk_bin_case(case):
+    """A small inline layout with integer vectors (every distance is an
+    exact integer in each mode, so ties are real) and one constant
+    projection level per block: with g = 0 and halves j + 1/2 a block of
+    level v lands whole in bin v (v = steps: never admitted).
+
+    ``dup_tables``: both tables share one layout and levels, and query 0
+    selects blocks 0 and 1 in each (identical (d², id) pairs).
+    ``tied_dists``: every vector is stored twice, under two ids.
+    ``bin_overflow``: every block in bin 1, so that bin takes the
+    query's 40 admitted slots over five blocks.
+    ``all_invalid``: query 1 selects no block at all."""
+    L, M, nb, B, K, d, steps = 2, 3, 4, 8, 4, 16, 4
+    lnb, n = L * nb, nb * B
+    rng = np.random.default_rng(14)
+    data = rng.integers(-3, 4, size=(n, d)).astype(np.float32)
+    if case == "tied_dists":
+        data[n // 2:] = data[: n // 2]
+    perms = [rng.permutation(n) for _ in range(L)]
+    levels = rng.integers(0, steps + 1, size=lnb)
+    if case == "dup_tables":
+        perms[1] = perms[0]
+        levels[nb:] = levels[:nb]
+    if case == "bin_overflow":
+        levels[:] = 1
+    ids_blocks = np.concatenate(perms).reshape(lnb, B).astype(np.int32)
+    proj_blocks = np.broadcast_to(
+        levels[:, None, None], (lnb, B, K)
+    ).astype(np.float32)
+    blk = np.stack([
+        np.concatenate([rng.permutation(nb)[:M] + l * nb for l in range(L)])
+        for _ in range(2)
+    ]).astype(np.int32)
+    blk[0, -1] = lnb  # one invalid slot
+    if case == "dup_tables":
+        blk[0] = [0, 1, 2, nb, nb + 1, lnb]
+    if case == "all_invalid":
+        blk[1] = lnb
+    q = rng.integers(-3, 4, size=(2, d)).astype(np.float32)
+    halves = np.arange(steps, dtype=np.float32) + 0.5
+    data, ids_blocks = jnp.asarray(data), jnp.asarray(ids_blocks)
+    vec_blocks = jnp.take(data, ids_blocks, axis=0)
+    norm_blocks = jnp.sum(jnp.square(vec_blocks), axis=-1)
+    return (data, ids_blocks, vec_blocks, norm_blocks, jnp.asarray(proj_blocks),
+            jnp.asarray(blk), jnp.zeros((2, L, K)), jnp.asarray(q),
+            jnp.asarray(halves), M, n)
+
+
+@pytest.mark.parametrize("mode", ["norm", "exact", "int8", "bf16"])
+@pytest.mark.parametrize(
+    "case", ["dup_tables", "tied_dists", "bin_overflow", "all_invalid"]
+)
+def test_fused_window_bins_in_order(case, mode):
+    """The bins hold each bin's ks smallest distinct (d², id) pairs in
+    order, ties to the smaller id, duplicates once; cnt exact."""
+    (data, ids_blocks, vec_blocks, norm_blocks, proj_blocks, blk, g, q,
+     halves, M, n) = _mk_bin_case(case)
+    quant = mode in ("int8", "bf16")
+    ks = 4 * 3 if quant else 3
+    xb, xs = (quantize_blocks(data, ids_blocks, mode) if quant
+              else (vec_blocks, None))
+    bd, bi, cnt = fused_window_search(
+        blk, halves, proj_blocks, xb, norm_blocks, ids_blocks, g, q,
+        M=M, ks=ks, n=n, mode=mode, interpret=True, x_scale=xs,
+    )
+    d2r, hwr = window_dist_ref(blk, proj_blocks, vec_blocks, norm_blocks,
+                               g, q, M, exact=(mode == "exact"))
+    if mode == "int8":
+        d2r = _int8_pool(q, xb, xs, norm_blocks, blk)
+    idsr = jnp.take(ids_blocks, blk, axis=0, mode="fill",
+                    fill_value=n).reshape(2, -1)
+    rd, ri, rc = fused_search_ref(d2r, hwr, idsr, halves, n, ks)
+    np.testing.assert_array_equal(np.asarray(cnt), rc)
+    np.testing.assert_array_equal(np.asarray(bi), ri)
+    np.testing.assert_allclose(np.asarray(bd), rd, rtol=1e-5, atol=1e-5)
+    # each case holds what it is named for
+    if case == "dup_tables":
+        adm = np.asarray(hwr[0]) < float(halves[-1])
+        pairs = list(zip(np.asarray(d2r[0])[adm], np.asarray(idsr[0])[adm]))
+        assert len(set(pairs)) < len(pairs)
+    if case == "tied_dists":
+        assert ((rd[:, :, 1:] == rd[:, :, :-1]) & np.isfinite(rd[:, :, 1:])).any()
+    if case == "bin_overflow":
+        assert rc[0, 1] > ks and np.isfinite(rd[0, 1]).all()
+    if case == "all_invalid":
+        assert (rc[1] == 0).all() and (ri[1] == n).all()
+
+
 def test_fused_window_search_int8_matches_ref():
     """int8 mode: integer dots are exact, so the kernel must match a jnp
     oracle that replays the same quantized arithmetic (same scales, same
@@ -298,17 +401,7 @@ def test_fused_window_search_int8_matches_ref():
         blk_idx, halves, proj_blocks, qb, norm_blocks, ids_blocks,
         g, q, M=M, ks=ks, n=n, mode="int8", interpret=True, x_scale=qsc,
     )
-    # oracle pool: same quantized dot, dequantized in the kernel's order
-    qv, qqs = _quantize_query(q, "int8")
-    xq = jnp.take(qb, blk_idx, axis=0, mode="fill", fill_value=0)
-    xs = jnp.take(qsc, blk_idx, axis=0, mode="fill", fill_value=1.0)
-    nrm = jnp.take(norm_blocks, blk_idx, axis=0, mode="fill", fill_value=jnp.inf)
-    idot = jnp.einsum("qsbd,qd->qsb", xq.astype(jnp.int32),
-                      qv.astype(jnp.int32)).astype(jnp.float32)
-    q2 = jnp.sum(jnp.square(q), axis=-1)
-    d2q = jnp.maximum(
-        nrm - 2.0 * (xs * qqs[:, :, None] * idot) + q2[:, None, None], 0.0
-    ).reshape(Q, -1)
+    d2q = _int8_pool(q, qb, qsc, norm_blocks, blk_idx)
     _, hwr = window_dist_ref(blk_idx, proj_blocks, vec_blocks, norm_blocks,
                              g, q, M)
     idsr = jnp.take(ids_blocks, blk_idx, axis=0, mode="fill",
