@@ -174,12 +174,19 @@ def _str_order(proj_t: jax.Array, block_size: int) -> jax.Array:
     return jnp.lexsort((key2, slab))
 
 
-def build(key: jax.Array, data: jax.Array, params: DBLSHParams) -> DBLSHIndex:
-    """Indexing phase (paper §IV-B): project into L K-dim spaces (Eq. 7),
-    then bulk-load one dense STR index per space."""
-    params = params.resolve()
+@partial(jax.jit, static_argnames="params")
+def _hash_and_pack(key: jax.Array, data: jax.Array, params: DBLSHParams):
+    """The hash functions, and each table's STR-ordered blocks: (proj_vecs,
+    orders, proj_blocks, ids_blocks, mbr_lo, mbr_hi).
+
+    One program, the key an argument so that one compile serves every
+    key.  Op by op, each intermediate (the (L, n, K) projections, their
+    sorted copy, its padded concatenation) is a buffer of its own in the
+    device's layout, and several are live at once beside the data: at
+    n = 10M that does not fit one 16 GB chip.  Every value here is a
+    projection (one dot per point and table, as op by op) or a copy of
+    one, so the arrays are the same bit for bit."""
     n, d = data.shape
-    assert n == params.n and d == params.d, (data.shape, params)
     B, K, L = params.block_size, params.K, params.L
     nb = -(-n // B)
     n_pad = nb * B
@@ -187,9 +194,8 @@ def build(key: jax.Array, data: jax.Array, params: DBLSHParams) -> DBLSHIndex:
     proj_vecs = hashing.sample_projections(key, d, K, L)
     proj = hashing.project(data, proj_vecs)  # (L, n, K)
 
-    orders = jax.vmap(lambda p: _str_order(p, B))(proj)  # (L, n)
-
-    def _pack(order, proj_t):
+    def _pack(proj_t):
+        order = _str_order(proj_t, B)
         p_sorted = jnp.take(proj_t, order, axis=0)
         pad = jnp.full((n_pad - n, K), jnp.inf, p_sorted.dtype)
         p_sorted = jnp.concatenate([p_sorted, pad], axis=0).reshape(nb, B, K)
@@ -201,9 +207,31 @@ def build(key: jax.Array, data: jax.Array, params: DBLSHParams) -> DBLSHIndex:
         finite = jnp.isfinite(p_sorted[..., :1])
         lo = jnp.min(p_sorted, axis=1)
         hi = jnp.max(jnp.where(finite, p_sorted, -jnp.inf), axis=1)
-        return p_sorted, ids, lo, hi
+        return order, p_sorted, ids, lo, hi
 
-    proj_blocks, ids_blocks, mbr_lo, mbr_hi = jax.vmap(_pack)(orders, proj)
+    # one table at a time: a table's sort and gather temporaries are a
+    # fifth of all tables' at once
+    return (proj_vecs,) + jax.lax.map(_pack, proj)
+
+
+def build(key: jax.Array, data: jax.Array, params: DBLSHParams) -> DBLSHIndex:
+    """Indexing phase (paper §IV-B): project into L K-dim spaces (Eq. 7),
+    then bulk-load one dense STR index per space.
+
+    The hashing and packing run as one program (``_hash_and_pack``); the
+    squared norms and the quantized blocks stay op by op, since inside a
+    larger program XLA may fuse their arithmetic into its neighbours and
+    round float32 differently."""
+    params = params.resolve()
+    n, d = data.shape
+    assert n == params.n and d == params.d, (data.shape, params)
+    B = params.block_size
+    nb = -(-n // B)
+    n_pad = nb * B
+
+    proj_vecs, orders, proj_blocks, ids_blocks, mbr_lo, mbr_hi = (
+        _hash_and_pack(key, data, params)
+    )
 
     if params.inline_vectors:
         def _pack_vecs(order):

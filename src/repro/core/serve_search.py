@@ -39,9 +39,23 @@ of once per radius:
 
 Three verify engines:
   * ``jnp``    — pure-XLA gather + verify (works everywhere; CPU default)
-  * ``kernel`` — Pallas ``candidate_dist`` on pre-gathered candidates
-  * ``inline`` — Pallas ``window_dist`` with scalar-prefetch block DMA
-                 (zero-copy gather; requires params.inline_vectors)
+  * ``kernel`` — Pallas ``fused_cand_search`` on pre-gathered candidates
+                 (either layout: the one-copy layout's Pallas engine)
+  * ``inline`` — Pallas ``fused_window_search`` with scalar-prefetch
+                 block DMA (zero-copy gather; requires
+                 params.inline_vectors)
+
+**The fused gathered step copies no (L, nb, ...) index array**
+(DESIGN.md §13).  The device keeps ``proj_blocks``, ``ids_blocks`` and
+``norm_blocks`` with nb as their minor dimension, so merging (L, nb)
+into one gather axis relays out the whole array on every call: 6.06
+GiB of step temporaries at n = 10M.  The ``kernel`` engine reads the
+selected blocks' slots out of the stored arrays instead
+(``_slot_gather``); the ``jnp`` pool path keeps the merge, which is
+twice as fast at a shard's size.  The gathers run under the
+``dblsh.gather`` scope inside ``dblsh.verify``, with the gather of the
+candidates' data rows by id.  The inline engine's operands are the
+kernel's own layout, and keep their relayout.
 
 ``search_batch_fixed_ref`` preserves the multi-pass (per-radius
 re-selection) algorithm verbatim: it is the equivalence oracle for the
@@ -192,65 +206,111 @@ def _select_blocks(index: DBLSHIndex, G: jax.Array, w):
     return jax.vmap(per_table)(index.mbr_lo, index.mbr_hi, jnp.swapaxes(G, 0, 1))
 
 
+def _slot_gather(a: jax.Array, blk_q: jax.Array, M: int, fill):
+    """The slots of the selected blocks, read from a stored per-table
+    block array without copying it.
+
+    a: (L, nb, B) or (L, nb, B, K); blk_q: (Qn, S) flattened cross-table
+    block ids (S = L·M, table-major; sentinel L·nb).  Returns (Qn, L, M,
+    B) or (Qn, L, M, B, K), ``fill`` on invalid slots.
+
+    The device keeps these arrays with nb as the minor dimension (B = 64
+    and K = 10 would pad to 128 lanes), so the trailing (B[, K]) slab of
+    a block is not contiguous there: merging (L, nb) into one gather axis
+    is a relayout of the whole array on every call.  The view with nb
+    last, (L, B, nb) or (L·K·B, nb), is the stored order, and each slot
+    is gathered as one element of it.  The proj gather runs one K column
+    at a time: as one 2-D element gather of Qn·S·B·K indices it compiles
+    for minutes."""
+    L, nb, B = a.shape[:3]
+    Qn = blk_q.shape[0]
+    bq = blk_q.reshape(Qn, L, M, 1)
+    t = jnp.arange(L, dtype=jnp.int32)[None, :, None, None]
+    ok = bq < L * nb
+    b = jnp.where(ok, bq - t * nb, 0)  # local block id, (Qn, L, M, 1)
+    j = jnp.arange(B, dtype=jnp.int32)
+    if a.ndim == 3:
+        x = jnp.swapaxes(a, 1, 2)[t, j, b]  # (Qn, L, M, B)
+        return jnp.where(ok, x, fill)
+    K = a.shape[3]
+    view = jnp.transpose(a, (0, 3, 2, 1)).reshape(L * K * B, nb)
+    cols = jax.lax.map(lambda c: view[(t * K + c) * B + j, b],
+                       jnp.arange(K, dtype=jnp.int32))  # (K, Qn, L, M, B)
+    return jnp.where(ok[..., None], jnp.moveaxis(cols, 0, -1), fill)
+
+
+def _gather_slots(index: DBLSHIndex, blk_q: jax.Array, dtype: str):
+    """What the gathered engines read of the selected slots, under the
+    ``dblsh.gather`` scope: projections (Qn, S, B, K) (+inf on invalid
+    slots), ids (Qn, S, B) (n), squared norms (Qn, S, B) (+inf), the
+    vectors (Qn, S, B, d) (0; the quantized blocks for a quantized
+    ``dtype``) and their dequant scales (Qn, S, B) (1; ``None`` for
+    fp32).  blk_q: (Qn, S) flattened cross-table block ids."""
+    p = index.params
+    L, M, B = p.L, p.max_blocks, p.block_size
+    Qn, S = blk_q.shape
+    lnb = L * index.nb
+
+    def blocks(a, fill):  # the inline layout's (L, nb, B, d) copies
+        return jnp.take(a.reshape(lnb, B, -1), blk_q, axis=0, mode="fill",
+                        fill_value=fill)
+
+    with jax.named_scope("dblsh.gather"):
+        pb = _slot_gather(index.proj_blocks, blk_q, M, _INF).reshape(
+            Qn, S, B, p.K)
+        ib = _slot_gather(index.ids_blocks, blk_q, M, index.n).reshape(
+            Qn, S, B)
+        nrm = _slot_gather(index.norm_blocks, blk_q, M, _INF).reshape(
+            Qn, S, B)
+        sc = None
+        if dtype != "fp32":
+            vb = blocks(index.qvec_blocks, 0)
+            sc = _slot_gather(index.qvec_scale, blk_q, M, 1.0).reshape(
+                Qn, S, B)
+        elif p.inline_vectors:
+            vb = blocks(index.vec_blocks, 0.0)
+        else:
+            vb = jnp.take(
+                index.data, ib.reshape(Qn, -1), axis=0, mode="fill",
+                fill_value=0.0,
+            ).reshape(Qn, S, B, -1)
+    return pb, ib, nrm, vb, sc
+
+
 def _gather_pool(index: DBLSHIndex, blk_q: jax.Array, G: jax.Array,
-                 Q: jax.Array, engine: str, exact: bool, interpret):
-    """Engine dispatch for the verify-once stage.
+                 Q: jax.Array, exact: bool):
+    """The 'jnp' engine's verify-once stage.
 
     blk_q: (Qn, S) flattened cross-table block ids (S = L·M, sentinel
-    L·nb). Returns (d2, hw): (Qn, C) exact squared distances and window
-    halfwidths over the C = S·B candidate slots, table-major. Slots are
-    *not* window-masked — the schedule applies per-step masks on hw."""
+    L·nb). Returns (d2, hw, ci): (Qn, C) exact squared distances, window
+    halfwidths and ids over the C = S·B candidate slots, table-major.
+    Slots are *not* window-masked — the schedule applies per-step masks
+    on hw."""
     p = index.params
-    nb = index.nb
     L, M, B = p.L, p.max_blocks, p.block_size
-    Qn = Q.shape[0]
-    S = L * M
-    proj_flat = index.proj_blocks.reshape(L * nb, B, p.K)
+    Qn, S = blk_q.shape
+    lnb = L * index.nb
 
-    if engine == "inline":
-        return kernels.window_dist(
-            blk_q,
-            proj_flat,
-            index.vec_blocks.reshape(L * nb, B, -1),
-            index.norm_blocks.reshape(L * nb, B),
-            G,
-            Q,
-            M=M,
-            exact=exact,
-            interpret=interpret,
-        )
+    # the whole-array (L·nb) merge, a relayout on every call: at a
+    # 2.5M-row shard it costs 1.5 GiB of temporaries but half the time of
+    # _slot_gather's element gathers (5.1 against 10.2 ms a batch of 32
+    # on a v5e), and the sharded placement runs this engine
+    def flat(a, fill):
+        return jnp.take(a.reshape((lnb,) + a.shape[2:]), blk_q, axis=0,
+                        mode="fill", fill_value=fill)
 
-    pb = jnp.take(proj_flat, blk_q, axis=0, mode="fill", fill_value=_INF)
-    if p.inline_vectors:
-        vb = jnp.take(
-            index.vec_blocks.reshape(L * nb, B, -1), blk_q, axis=0,
-            mode="fill", fill_value=0.0,
-        )  # (Qn, S, B, d)
-    else:
-        ib = jnp.take(
-            index.ids_blocks.reshape(L * nb, B), blk_q, axis=0,
-            mode="fill", fill_value=index.n,
-        )
-        vb = jnp.take(
-            index.data, ib.reshape(Qn, -1), axis=0, mode="fill", fill_value=0.0
-        ).reshape(Qn, S, B, -1)
-    nrm = jnp.take(
-        index.norm_blocks.reshape(L * nb, B), blk_q, axis=0,
-        mode="fill", fill_value=_INF,
-    )  # (Qn, S, B)
+    with jax.named_scope("dblsh.gather"):
+        pb = flat(index.proj_blocks, _INF)
+        ib = flat(index.ids_blocks, index.n)
+        nrm = flat(index.norm_blocks, _INF)
+        if p.inline_vectors:
+            vb = flat(index.vec_blocks, 0.0)
+        else:
+            vb = jnp.take(
+                index.data, ib.reshape(Qn, -1), axis=0, mode="fill",
+                fill_value=0.0,
+            ).reshape(Qn, S, B, -1)
 
-    if engine == "kernel":
-        return kernels.candidate_dist(
-            pb.reshape(Qn, L, M * B, p.K),
-            vb.reshape(Qn, L, M * B, -1),
-            nrm.reshape(Qn, L, M * B),
-            G,
-            Q,
-            exact=exact,
-            interpret=interpret,
-        )
-
-    # 'jnp'
     g_rep = jnp.repeat(G, M, axis=1)  # (Qn, S, K)
     hw = jnp.max(jnp.abs(pb - g_rep[:, :, None, :]), axis=-1)  # (Qn, S, B)
     C = S * B
@@ -267,7 +327,7 @@ def _gather_pool(index: DBLSHIndex, blk_q: jax.Array, G: jax.Array,
         d2 = jnp.maximum(
             nrm - 2.0 * dots + q2[:, None, None], 0.0
         )
-    return d2.reshape(Qn, C), hw.reshape(Qn, C)
+    return d2.reshape(Qn, C), hw.reshape(Qn, C), ib.reshape(Qn, C)
 
 
 def _fused_bins(index: DBLSHIndex, blk_q: jax.Array, G: jax.Array,
@@ -295,9 +355,6 @@ def _fused_bins(index: DBLSHIndex, blk_q: jax.Array, G: jax.Array,
     n = index.n
     S = L * M
     mode = ("exact" if exact else "norm") if dtype == "fp32" else dtype
-    proj_flat = index.proj_blocks.reshape(L * nb, B, p.K)
-    nrm_flat = index.norm_blocks.reshape(L * nb, B)
-    ids_flat = index.ids_blocks.reshape(L * nb, B)
 
     if engine == "inline":
         if dtype == "fp32":
@@ -306,34 +363,13 @@ def _fused_bins(index: DBLSHIndex, blk_q: jax.Array, G: jax.Array,
             xb = index.qvec_blocks.reshape(L * nb, B, -1)
             xs = index.qvec_scale.reshape(L * nb, B)
         return kernels.fused_window_search(
-            blk_q, halves, proj_flat, xb, nrm_flat, ids_flat, G, Q,
+            blk_q, halves, index.proj_blocks.reshape(L * nb, B, p.K), xb,
+            index.norm_blocks.reshape(L * nb, B),
+            index.ids_blocks.reshape(L * nb, B), G, Q,
             M=M, ks=ks, n=n, mode=mode, interpret=interpret, x_scale=xs,
         )
 
-    pb = jnp.take(proj_flat, blk_q, axis=0, mode="fill", fill_value=_INF)
-    ib = jnp.take(ids_flat, blk_q, axis=0, mode="fill", fill_value=n)
-    nrm = jnp.take(nrm_flat, blk_q, axis=0, mode="fill", fill_value=_INF)
-    if dtype == "fp32":
-        if p.inline_vectors:
-            vb = jnp.take(
-                index.vec_blocks.reshape(L * nb, B, -1), blk_q, axis=0,
-                mode="fill", fill_value=0.0,
-            )
-        else:
-            vb = jnp.take(
-                index.data, ib.reshape(Qn, -1), axis=0, mode="fill",
-                fill_value=0.0,
-            ).reshape(Qn, S, B, -1)
-        sc = None
-    else:
-        vb = jnp.take(
-            index.qvec_blocks.reshape(L * nb, B, -1), blk_q, axis=0,
-            mode="fill", fill_value=0,
-        )
-        sc = jnp.take(
-            index.qvec_scale.reshape(L * nb, B), blk_q, axis=0,
-            mode="fill", fill_value=1.0,
-        )
+    pb, ib, nrm, vb, sc = _gather_slots(index, blk_q, dtype)
 
     if engine == "kernel":
         return kernels.fused_cand_search(
@@ -397,9 +433,10 @@ def _rerank_bins(index: DBLSHIndex, Q: jax.Array, bins_d, bins_i):
     n = index.n
     Qn, steps, ks = bins_d.shape
     ids = bins_i.reshape(Qn, steps * ks)
-    x = jnp.take(
-        index.data, ids, axis=0, mode="fill", fill_value=0.0
-    ).reshape(Qn, steps, ks, -1)
+    with jax.named_scope("dblsh.gather"):
+        x = jnp.take(
+            index.data, ids, axis=0, mode="fill", fill_value=0.0
+        ).reshape(Qn, steps, ks, -1)
     nrm = jnp.sum(jnp.square(x), axis=-1)
     dots = jnp.sum(x * Q[:, None, None, :], axis=-1)
     q2 = jnp.sum(jnp.square(Q), axis=-1)
@@ -571,12 +608,7 @@ def search_batch_fixed(
             # C1 admission count at step j == slots in bins 0..j
             cum_adm = jnp.cumsum(bin_cnt, axis=1)
         else:
-            ci = jnp.take(
-                index.ids_blocks.reshape(L * nb, B), blk_q, axis=0,
-                mode="fill", fill_value=n,
-            ).reshape(Qn, L * M * B)
-            d2, hw = _gather_pool(index, blk_q, G, Q, engine, exact,
-                                  interpret)
+            d2, hw, ci = _gather_pool(index, blk_q, G, Q, exact)
 
     bhw_q = jnp.swapaxes(bhw, 0, 1).reshape(Qn, L * M)  # (Qn, S)
 

@@ -3,7 +3,8 @@
 The only test file that knows about the chip.  Interpret-mode tests
 check what the kernels compute; these check that the TPU compiler
 accepts them at the widths the store serves (SIFT1M shape: n=1M, d=128,
-B=64, K=10, L=5, M=5, steps=8), and that one call fits a v5e's HBM.
+B=64, K=10, L=5, M=5, steps=8; and SIFT10M whole on one chip, n=10M,
+M=20, one copy of the vectors), and that one call fits a v5e's HBM.
 Nothing runs: a compile that passes here is not a chip run.
 
 The topology is described inside a module-scoped fixture, never at
@@ -12,6 +13,7 @@ worker that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import pytest
 
@@ -21,8 +23,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import kernels
 from repro.core import DBLSHParams, build, search_batch_fixed
+from repro.core.index import _hash_and_pack
 
 N, D, B, K, L, M, STEPS, k = 1_000_000, 128, 64, 10, 5, 5, 8, 10
+N10, M10 = 10_000_000, 20  # SIFT10M whole on one chip, one copy
 LNB = L * -(-N // B)
 HBM_LIMIT = 15 * 2**30  # a v5e holds 16 GB; leave room for the runtime
 BATCHES = (1, 8, 32)
@@ -100,16 +104,25 @@ def test_fused_cand_search_compiles(one_chip, Qn):
     _check(jax.jit(f).lower(*args).compile(), kernel=True)
 
 
-@pytest.mark.parametrize("engine", ["jnp", "inline"])
-def test_search_step_compiles(one_chip, engine):
-    """The whole served step (project, select, verify, merge) at n=1M."""
+@pytest.mark.parametrize("engine,n,max_blocks,inline", [
+    pytest.param("jnp", N, 0, True, id="jnp"),
+    pytest.param("inline", N, 0, True, id="inline"),
+    # the one-copy layout whole on one chip (sift10m-1chip)
+    pytest.param("kernel", N10, M10, False, id="kernel-onecopy-10M"),
+])
+def test_search_step_compiles(one_chip, engine, n, max_blocks, inline):
+    """The whole served step (project, select, verify, merge): at n=1M
+    (SIFT1M, inline layout), and at n=10M on the one-copy layout, whose
+    step must copy no (L, nb, ...) index array: its temporaries stay
+    small beside the 7.1 GiB of data and index."""
     params = DBLSHParams.derive(
-        n=N, d=D, c=1.5, t=64, k=k, K=K, L=L, inline_vectors=True
+        n=n, d=D, c=1.5, t=64, k=k, K=K, L=L, inline_vectors=inline,
+        max_blocks=max_blocks,
     )
-    assert params.max_blocks == M
+    assert params.max_blocks == (max_blocks or M)
     shapes = jax.eval_shape(
         lambda key, x: build(key, x, params),
-        jax.random.key(0), jax.ShapeDtypeStruct((N, D), jnp.float32),
+        jax.random.key(0), jax.ShapeDtypeStruct((n, D), jnp.float32),
     )
     index = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), shapes)
     compiled = search_batch_fixed.lower(
@@ -117,3 +130,25 @@ def test_search_step_compiles(one_chip, engine):
         engine=engine, interpret=False, with_stats=True,
     ).compile()
     _check(compiled, kernel=engine != "jnp")
+    if not inline:
+        assert compiled.memory_analysis().temp_size_in_bytes <= 2**30
+        nb = -(-n // B)
+        entry = compiled.as_text().split("ENTRY", 1)[1]
+        assert not re.search(rf"= \S+\[{L},{nb}[,\]]\S* copy\(", entry)
+
+
+def test_local_build_compiles(one_chip):
+    """The local build's hashing and packing at n=10M, as one program:
+    data, temporaries and the index fit one chip together."""
+    params = DBLSHParams.derive(
+        n=N10, d=D, c=1.5, t=64, k=k, K=K, L=L, inline_vectors=False,
+        max_blocks=M10,
+    ).resolve()
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = _hash_and_pack.lower(
+        _sds(one_chip, key.shape, key.dtype), _sds(one_chip, (N10, D)), params,
+    ).compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes)
+    assert used < HBM_LIMIT, f"{used / 2**30:.2f} GiB of HBM"
